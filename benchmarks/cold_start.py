@@ -4,37 +4,41 @@ Three serving arms, each booted in a fresh subprocess (a fresh process
 is the only honest "cold": jit caches, dispatcher memos, and the
 per-shape executable caches are all process-global):
 
-  cold         — lazy server, no warmup, no persistent cache: the first
-                 request per bucket pays lower+compile in-band.
-  warmed       — ``warmup="sync"`` over the bucket grid: compiles run at
-                 boot, the first request dispatches a warm executable.
-  disk_restart — ``warmup="sync"`` with ``REPRO_COMPILE_CACHE_DIR``; the
-                 arm is the SECOND boot against the same cache dir, so
-                 its warmup is served from disk (zero fresh XLA
-                 compiles, asserted on the jax compilation-cache
-                 counters — never timing).
+  cold         — lazy server, no warmup, an empty compile cache: the
+                 first request per bucket pays lower+compile in-band.
+  warmed       — ``warmup="sync"`` over the bucket grid, an empty compile
+                 cache: compiles run at boot, the first request
+                 dispatches a warm executable.
+  disk_restart — ``warmup="sync"``; the arm is the SECOND boot against
+                 the same ``JAX_COMPILATION_CACHE_DIR``, so its warmup is
+                 served from disk (zero fresh XLA compiles, asserted on
+                 the jax compilation-cache counters — never timing).
+
+Each arm's cache directory is a fixed path under ``.cache/cold_start``,
+emptied before the arm's first boot.
 
 Per arm, per bucket: first-request latency, then steady-state p50/p99
 over repeated single-request round trips; plus boot-to-ready and
 boot-to-first-solve walls. The headline derived number is
 ``first/steady-p50`` — the cliff ratio the warmup is meant to kill.
 
-All arms run single-request micro-batches on this host's CPU backend;
-the report is about *relative* first-hit vs steady-state shape, not
-absolute device throughput (honest-labeling rule, DESIGN.md §10).
+All arms run single-request micro-batches; the report names the device
+the arms ran on, and is about *relative* first-hit vs steady-state shape
+(honest-labeling rule, DESIGN.md §10).
 """
 from __future__ import annotations
 
 import json
 import os
+import shutil
 import subprocess
 import sys
-import tempfile
 
-from benchmarks.common import RESULTS_DIR, save_report
+from benchmarks.common import save_report
 
-SRC = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "src")
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+SRC = os.path.join(REPO_ROOT, "src")
+CACHE_ROOT = os.path.join(REPO_ROOT, ".cache", "cold_start")
 
 # Child process: boot one serving arm, time first hits + steady state.
 # `_T0` is bound before any heavy import so boot walls include them.
@@ -98,17 +102,22 @@ for bucket, (lo, hi) in ((16, (12, 15)), (32, (20, 30))):
 out["boot_to_first_solve_s"] = round(first_solve_done, 3)
 out["executor_compiles"] = EX.executor_compile_count()
 out["compile_cache"] = aot.cache_stats()
+dev = jax.devices()[0]
+out["device"] = {"platform": dev.platform, "kind": dev.device_kind,
+                 "count": jax.device_count()}
 print("RESULT " + json.dumps(out))
 """
 
 
-def _boot(arm: str, steady_n: int, cache_dir: str = "") -> dict:
-    env = dict(os.environ,
+def _boot(arm: str, steady_n: int, fresh: bool = True) -> dict:
+    """One arm's boot in a child process, against the arm's fixed cache
+    directory (emptied first when `fresh`)."""
+    cache_dir = os.path.join(CACHE_ROOT, arm)
+    if fresh:
+        shutil.rmtree(cache_dir, ignore_errors=True)
+    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=cache_dir,
                PYTHONPATH=SRC + os.pathsep + os.environ.get("PYTHONPATH",
                                                             ""))
-    env.pop("REPRO_COMPILE_CACHE_DIR", None)
-    if cache_dir:
-        env["REPRO_COMPILE_CACHE_DIR"] = cache_dir
     out = subprocess.run(
         [sys.executable, "-c", CHILD, arm, str(steady_n)],
         env=env, capture_output=True, text=True, timeout=1200)
@@ -126,10 +135,8 @@ def run(full: bool = False, steady_n: int = None):
     report = {"steady_n": steady_n, "arms": {}}
     report["arms"]["cold"] = _boot("cold", steady_n)
     report["arms"]["warmed"] = _boot("warmed", steady_n)
-    with tempfile.TemporaryDirectory() as d:
-        cache = os.path.join(d, "xla-cache")
-        priming = _boot("disk_restart", steady_n, cache_dir=cache)
-        restart = _boot("disk_restart", steady_n, cache_dir=cache)
+    priming = _boot("disk_restart", steady_n)
+    restart = _boot("disk_restart", steady_n, fresh=False)
     restart["priming_boot_to_ready_s"] = priming["boot_to_ready_s"]
     report["arms"]["disk_restart"] = restart
     # Counter-based warm-restart proof: the second boot's entire grid
@@ -137,8 +144,10 @@ def run(full: bool = False, steady_n: int = None):
     report["warm_restart_zero_fresh_compiles"] = bool(
         restart["compile_cache"]["misses"] == 0
         and restart["compile_cache"]["hits"] > 0)
-    report["note"] = ("single-host CPU backend; relative first-hit vs "
-                      "steady-state shape, not device throughput")
+    dev = restart["device"]
+    report["device"] = dev
+    report["note"] = (f"{dev['platform']} ({dev['kind']}); relative "
+                      "first-hit vs steady-state shape")
     save_report("cold_start", report)
     rows = []
     for arm, data in report["arms"].items():

@@ -10,19 +10,20 @@
 #   REPRO_SOLVE_EXECUTOR=sharded scripts/run_server.sh my_server.py
 #
 # Knobs (defaults below, see DESIGN.md for the sections that own them):
-#   REPRO_COMPILE_CACHE_DIR  persistent XLA compile cache (§12): restarts
-#                            rebuild the executable grid from disk with
-#                            zero fresh compiles. Default: .cache/xla
-#                            under the repo root.
+#   JAX_COMPILATION_CACHE_DIR  persistent XLA compile cache (§12):
+#                            restarts rebuild the executable grid from
+#                            disk with zero fresh compiles. Unset: the
+#                            server keeps it at .cache/xla under the repo
+#                            root.
 #   REPRO_SOLVE_EXECUTOR     solve executor registry name (§7):
-#                            local | sharded. Default: local.
+#                            local | sharded (a mesh over every device
+#                            the host exposes). Default: local.
 #   REPRO_PRECISION_BACKEND  precision backend registry name (§6):
-#                            jnp | pallas | ... Default: process default.
-#   JAX_ENABLE_X64           the solvers' fp64 carrier (§2). Pinned on —
-#                            the bit-parity contract assumes it.
-#   XLA_FLAGS                host-device count for the sharded executor
-#                            is appended here when REPRO_SOLVE_EXECUTOR
-#                            is sharded and no count was given.
+#                            jnp | pallas | ... Default: the platform's
+#                            (compiled pallas on a TPU, jnp elsewhere).
+#   JAX_ENABLE_X64           the solvers' fp64 carrier on CPU hosts (§2).
+#                            Pinned on — the bit-parity contract assumes
+#                            it. A TPU serves the f32 carrier.
 set -euo pipefail
 
 REPO_ROOT="$(cd "$(dirname "${BASH_SOURCE[0]}")/.." && pwd)"
@@ -48,27 +49,8 @@ fi
 export JAX_ENABLE_X64="${JAX_ENABLE_X64:-1}"
 export TF_CPP_MIN_LOG_LEVEL="${TF_CPP_MIN_LOG_LEVEL:-4}"
 
-# --- persistent compile cache (DESIGN.md §12) --------------------------
-export REPRO_COMPILE_CACHE_DIR="${REPRO_COMPILE_CACHE_DIR:-$REPO_ROOT/.cache/xla}"
-mkdir -p "$REPRO_COMPILE_CACHE_DIR"
-
 # --- executor / backend selection (DESIGN.md §6-§7) --------------------
 export REPRO_SOLVE_EXECUTOR="${REPRO_SOLVE_EXECUTOR:-local}"
-if [[ -n "${REPRO_PRECISION_BACKEND:-}" ]]; then
-    export REPRO_PRECISION_BACKEND
-fi
-if [[ "$REPRO_SOLVE_EXECUTOR" == "sharded" \
-      && "${XLA_FLAGS:-}" != *host_platform_device_count* ]]; then
-    # A host-device mesh for the sharded executor on CPU hosts; real
-    # accelerator fleets already expose their devices and skip this.
-    export XLA_FLAGS="${XLA_FLAGS:-} --xla_force_host_platform_device_count=${REPRO_HOST_DEVICES:-8}"
-fi
-
-# --- XLA host tuning ---------------------------------------------------
-# Donated-buffer reuse + multi-threaded Eigen GEMMs are defaults today;
-# the one knob that reliably helps the solver's many small CPU
-# executables is keeping compilation parallel.
-export XLA_FLAGS="${XLA_FLAGS:-} --xla_cpu_use_thunk_runtime=true"
 
 export PYTHONPATH="$REPO_ROOT/src${PYTHONPATH:+:$PYTHONPATH}"
 

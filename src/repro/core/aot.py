@@ -18,8 +18,9 @@ kills the cliff three ways:
     traffic read from a trajectory log when one exists), so the
     likeliest buckets go warm first and the server's `/readyz`
     warm-bucket gate flips per bucket as each cell lands.
-  * `enable_persistent_cache()` — `jax.experimental.compilation_cache`
-    wiring (``REPRO_COMPILE_CACHE_DIR``): restarts reuse compiles from
+  * `enable_persistent_cache()` — jax's persistent compilation cache,
+    in ``JAX_COMPILATION_CACHE_DIR`` when that is set and otherwise at
+    the fixed ``<checkout>/.cache/xla``: restarts reuse compiles from
     disk, with hit/miss events mirrored into `repro.obs` counters so
     "the warm restart did zero fresh XLA compiles" is a counter
     assertion, not a timing guess. This also makes the §11 crash
@@ -32,9 +33,10 @@ import json
 import os
 import threading
 import time
+from pathlib import Path
 from typing import Callable, Dict, List, Optional, Sequence
 
-ENV_CACHE_DIR = "REPRO_COMPILE_CACHE_DIR"
+ENV_CACHE_DIR = "JAX_COMPILATION_CACHE_DIR"
 
 _cache_dir: Optional[str] = None
 _cache_events = {"hits": 0, "misses": 0}
@@ -58,32 +60,38 @@ def _count(name: str, help: str, amount: float = 1.0, **labels) -> None:
 # ---------------------------------------------------------------------------
 
 
-def enable_persistent_cache(cache_dir: Optional[str] = None
-                            ) -> Optional[str]:
-    """Point jax's persistent compilation cache at `cache_dir` (or
-    ``$REPRO_COMPILE_CACHE_DIR``); returns the directory in force, or
-    None when neither is set (no-op). Idempotent.
+def default_cache_dir() -> str:
+    """The cache directory when ``JAX_COMPILATION_CACHE_DIR`` is unset:
+    a fixed path inside the checkout. It must not move between runs —
+    a restart finds its compiles again only at the same path."""
+    return str(Path(__file__).resolve().parents[3] / ".cache" / "xla")
+
+
+def enable_persistent_cache() -> str:
+    """Turn on jax's persistent compilation cache and return the
+    directory in force. ``JAX_COMPILATION_CACHE_DIR``, when set, is that
+    directory — jax reads it itself and nothing here overrides it;
+    otherwise `default_cache_dir()`. Idempotent.
 
     The size/time thresholds are dropped to zero: the repro's grid is
-    many small CPU executables — exactly the entries jax's defaults
-    decline to persist — and the whole point is that a restarted server
+    many small executables — exactly the entries jax's defaults decline
+    to persist — and the whole point is that a restarted server
     rebuilds its grid from disk instead of re-running XLA."""
     global _cache_dir
-    d = cache_dir if cache_dir is not None else os.environ.get(ENV_CACHE_DIR)
-    if not d:
-        return _cache_dir
-    d = os.path.abspath(d)
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+    from_env = os.environ.get(ENV_CACHE_DIR)
+    d = from_env or default_cache_dir()
     if _cache_dir == d:
         return d
-    os.makedirs(d, exist_ok=True)
-    import jax
-    jax.config.update("jax_compilation_cache_dir", d)
-    for knob, val in (("jax_persistent_cache_min_compile_time_secs", 0.0),
-                      ("jax_persistent_cache_min_entry_size_bytes", -1)):
-        try:
-            jax.config.update(knob, val)
-        except Exception:      # knob renamed/absent on this jax version
-            pass
+    if not from_env:
+        os.makedirs(d, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", d)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    # A compile that ran before this point initialised the cache without
+    # these settings; start it afresh so they hold from here on.
+    compilation_cache.reset_cache()
     _install_listener()
     _cache_dir = d
     return d
@@ -104,7 +112,7 @@ def _install_listener() -> None:
                 _cache_events["hits"] += 1
                 _count("repro_compile_cache_hits_total",
                        "Persistent-compilation-cache hits (XLA compile "
-                       "served from REPRO_COMPILE_CACHE_DIR).")
+                       "served from the cache directory).")
             elif event.endswith("/cache_misses"):
                 _cache_events["misses"] += 1
                 _count("repro_compile_cache_misses_total",

@@ -268,21 +268,6 @@ class LocalExecutor(SolveExecutor):
         return 1
 
 
-def _shard_map(fn, mesh, in_specs, out_specs):
-    """shard_map across jax versions (kwarg renamed check_rep ->
-    check_vma when it moved to the jax namespace)."""
-    if hasattr(jax, "shard_map"):
-        try:
-            return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=False)
-        except TypeError:
-            return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
-
-
 # One Mesh per (data, model) shape per process: Mesh construction is
 # cheap but identity matters for jit cache reuse across executor
 # instances that hash equal.
@@ -488,7 +473,9 @@ class _MeshDispatch(_BatchDispatch):
         def data_sharded(*arrays):
             in_specs = tuple(P("data", *([None] * (a.ndim - 1)))
                              for a in arrays)
-            return _shard_map(fn, mesh, in_specs, P("data"))(*arrays)
+            return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                                 out_specs=P("data"),
+                                 check_vma=False)(*arrays)
 
         self._jit = data_sharded   # compile-accounting hook for tests
 

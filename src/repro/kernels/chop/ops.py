@@ -1,7 +1,6 @@
 """Jitted public wrapper for the chop kernel: format-id -> SMEM params."""
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -19,15 +18,20 @@ _FMT_PACKED = np.stack([
 ], axis=1)
 
 
-def make_fmt_params(fmt_id) -> jnp.ndarray:
-    """int32[4] SMEM parameter row for a (possibly traced) format id."""
-    return jnp.asarray(_FMT_PACKED)[jnp.asarray(fmt_id, jnp.int32)]
+def make_fmt_params(fmt_id, chop_out=None) -> jnp.ndarray:
+    """int32[1, 4] SMEM parameter row for a (possibly traced) format id;
+    with `chop_out` given, a fifth column carries that flag (the fused
+    matmul kernels' output-rounding switch)."""
+    row = jnp.asarray(_FMT_PACKED)[jnp.asarray(fmt_id, jnp.int32)]
+    if chop_out is not None:
+        row = jnp.concatenate(
+            [row, jnp.asarray([1 if chop_out else 0], jnp.int32)])
+    return row.reshape(1, -1)
 
 
 def chop_op(x: jnp.ndarray, fmt_id, *, block_rows: int = BLOCK_ROWS,
-            interpret: bool | None = None) -> jnp.ndarray:
-    """Round `x` (f32) to the format selected by the runtime `fmt_id`."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+            interpret: bool = False) -> jnp.ndarray:
+    """Round `x` (f32) to the format selected by the runtime `fmt_id`.
+    `interpret=True` runs the Pallas interpreter (CPU tests)."""
     return chop_pallas(x, make_fmt_params(fmt_id), block_rows=block_rows,
                        interpret=interpret)

@@ -1,21 +1,13 @@
 """Jitted public wrapper for qmatmul: padding + format-id -> SMEM params."""
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.chop.ops import _FMT_PACKED
+from repro.kernels.chop.ops import make_fmt_params
 
 from .qmatmul import (DEFAULT_BK, DEFAULT_BM, DEFAULT_BN, LANE, QMV_BM,
                       qmatmul_pallas, qmv_pallas)
-
-
-def make_fmt_params(fmt_id, chop_out: bool = True) -> jnp.ndarray:
-    """int32[5] = [t, emin, xmax_bits, saturate, chop_out]."""
-    row = jnp.asarray(_FMT_PACKED)[jnp.asarray(fmt_id, jnp.int32)]
-    return jnp.concatenate(
-        [row, jnp.asarray([1 if chop_out else 0], jnp.int32)])
 
 
 def _pad_to(x, m0, m1):
@@ -28,15 +20,13 @@ def _pad_to(x, m0, m1):
 
 def qmv_op(a: jnp.ndarray, v: jnp.ndarray, fmt_id, *,
            chop_out: bool = True, bm: int | None = None,
-           interpret: bool | None = None) -> jnp.ndarray:
+           interpret: bool = False) -> jnp.ndarray:
     """Fused chopped matvec for arbitrary (M, K) x (K,) f32 operands.
 
     Pads K to the LANE multiple shared with `ref.qmv_ref` (the reduction
     shape is part of the bit-exactness contract, DESIGN.md §6.2) and M to
     the row-block multiple, then runs the single-K-block row-sum kernel.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     if a.dtype != jnp.float32 or v.dtype != jnp.float32:
         raise TypeError("qmv_op targets the f32 TPU carrier; got "
                         f"{a.dtype} x {v.dtype}")
@@ -59,7 +49,7 @@ QGEMM_MAX_KP = 512
 def qgemm_op(a: jnp.ndarray, b: jnp.ndarray, fmt_id, *,
              chop_out: bool = True, bm: int | None = None,
              bn: int | None = None,
-             interpret: bool | None = None) -> jnp.ndarray:
+             interpret: bool = False) -> jnp.ndarray:
     """Pinned-contract chopped GEMM for (M, K) x (K, N) f32 operands —
     the `backend.chop_matmul` fast path (DESIGN.md §6.2).
 
@@ -71,8 +61,6 @@ def qgemm_op(a: jnp.ndarray, b: jnp.ndarray, fmt_id, *,
     beyond `QGEMM_MAX_KP` route to the oracle (bit-identical by the same
     contract — a pure VMEM-budget choice).
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     if a.dtype != jnp.float32 or b.dtype != jnp.float32:
         raise TypeError("qgemm_op targets the f32 TPU carrier; got "
                         f"{a.dtype} x {b.dtype}")
@@ -94,10 +82,8 @@ def qgemm_op(a: jnp.ndarray, b: jnp.ndarray, fmt_id, *,
 def qmatmul_op(a: jnp.ndarray, b: jnp.ndarray, fmt_id, *,
                chop_out: bool = True, bm: int | None = None,
                bn: int | None = None, bk: int | None = None,
-               interpret: bool | None = None) -> jnp.ndarray:
+               interpret: bool = False) -> jnp.ndarray:
     """Mixed-precision-emulated matmul for arbitrary (M,K)x(K,N) f32."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     M, K = a.shape
     _, N = b.shape
     bm = min(bm or DEFAULT_BM, max(8, 1 << int(np.ceil(np.log2(max(M, 1))))))

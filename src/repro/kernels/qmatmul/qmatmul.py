@@ -19,10 +19,11 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.precision.chop import _chop_core
+from repro.kernels.chop.chop import block_spec, fmt_spec, ref_chop
 
 from .ref import LANE
 
@@ -32,25 +33,22 @@ DEFAULT_BK = 256
 
 
 def _qmatmul_kernel(fmt_ref, a_ref, b_ref, o_ref, acc_ref):
-    """fmt_ref (SMEM): int32[5] = [t, emin, xmax_bits, saturate, chop_out]."""
-    t = fmt_ref[0]
-    emin = fmt_ref[1]
-    xmax_bits = fmt_ref[2].astype(jnp.uint32)
-    saturate = fmt_ref[3] != 0
+    """fmt_ref (SMEM): int32[1, 5] = [[t, emin, xmax_bits, saturate,
+    chop_out]]."""
+    chop = ref_chop(fmt_ref)
 
     @pl.when(pl.program_id(2) == 0)
     def _zero():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    a = _chop_core(a_ref[...], t, emin, 0, xmax_bits, saturate)
-    b = _chop_core(b_ref[...], t, emin, 0, xmax_bits, saturate)
-    acc_ref[...] += jnp.dot(a, b, preferred_element_type=jnp.float32)
+    acc_ref[...] += jnp.dot(chop(a_ref[...]), chop(b_ref[...]),
+                            preferred_element_type=jnp.float32,
+                            precision=lax.Precision.HIGHEST)
 
     @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
     def _emit():
         acc = acc_ref[...]
-        chopped = _chop_core(acc, t, emin, 0, xmax_bits, saturate)
-        o_ref[...] = jnp.where(fmt_ref[4] != 0, chopped, acc)
+        o_ref[...] = jnp.where(fmt_ref[0, 4] != 0, chop(acc), acc)
 
 
 QMV_BM = 256  # rows of A per grid step (multiple of LANE)
@@ -59,8 +57,8 @@ QMV_BM = 256  # rows of A per grid step (multiple of LANE)
 def _qmv_kernel(fmt_ref, a_ref, v_ref, o_ref):
     """Fused chopped matvec tile: chop operands in VMEM, multiply, row-sum.
 
-    fmt_ref (SMEM): int32[5] = [t, emin, xmax_bits, saturate, chop_out].
-    a_ref: (bm, Kp) tile of A; v_ref: (1, Kp); o_ref: (bm // LANE, LANE).
+    fmt_ref (SMEM): int32[1, 5] = [[t, emin, xmax_bits, saturate,
+    chop_out]]. a_ref: (bm, Kp) tile of A; v_ref: (1, Kp); o_ref: (bm, 1).
 
     The reduction is the VPU-friendly row-sum over the full (lane-padded)
     K axis in one block — deliberately NOT an MXU dot: a matvec is
@@ -73,36 +71,34 @@ def _qmv_kernel(fmt_ref, a_ref, v_ref, o_ref):
     to tiling over rows, so the grid over M does not perturb results.
     """
     from repro.precision import fma_barrier, tree_sum
-    t = fmt_ref[0]
-    emin = fmt_ref[1]
-    xmax_bits = fmt_ref[2].astype(jnp.uint32)
-    saturate = fmt_ref[3] != 0
-    a = _chop_core(a_ref[...], t, emin, 0, xmax_bits, saturate)
-    v = _chop_core(v_ref[...], t, emin, 0, xmax_bits, saturate)
-    out = tree_sum(fma_barrier(a * v), axis=1)         # carrier accumulation
-    chopped = _chop_core(out, t, emin, 0, xmax_bits, saturate)
-    out = jnp.where(fmt_ref[4] != 0, chopped, out)
-    o_ref[...] = out.reshape(o_ref.shape)
+    chop = ref_chop(fmt_ref)
+    # Carrier accumulation, kept as a (bm, 1) column: the row-sum lands
+    # on sublanes, and the column is what the store takes without a
+    # relayout.
+    out = tree_sum(fma_barrier(chop(a_ref[...]) * chop(v_ref[...])),
+                   axis=1, keepdims=True)
+    o_ref[...] = jnp.where(fmt_ref[0, 4] != 0, chop(out), out)
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "interpret"))
 def qmv_pallas(a: jnp.ndarray, v: jnp.ndarray, fmt_params: jnp.ndarray,
-               *, bm: int = QMV_BM, interpret: bool = True) -> jnp.ndarray:
+               *, bm: int = QMV_BM, interpret: bool = False) -> jnp.ndarray:
     """a: (Mp, Kp) f32, v: (1, Kp) f32 — padded by ops.qmv_op so that
-    Mp % bm == 0, Kp % LANE == 0, bm % LANE == 0. fmt_params: int32[5].
-    Returns the fused chopped matvec as (Mp,)."""
+    Mp % bm == 0, Kp % LANE == 0, bm % LANE == 0. fmt_params:
+    int32[1, 5]. Returns the fused chopped matvec as (Mp,)."""
     M, K = a.shape
     assert M % bm == 0 and K % LANE == 0 and bm % LANE == 0
     out = pl.pallas_call(
         _qmv_kernel,
         grid=(M // bm,),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((bm, K), lambda i: (i, 0)),
-            pl.BlockSpec((1, K), lambda i: (0, 0)),
+            fmt_spec(fmt_params.shape[-1]),
+            block_spec((bm, K), lambda i: (i, 0)),
+            block_spec((1, K), lambda i: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((bm // LANE, LANE), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((M // LANE, LANE), jnp.float32),
+        out_specs=block_spec((bm, 1), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((M, 1), jnp.float32),
+        name="qmv",
         interpret=interpret,
     )(fmt_params, a, v)
     return out.reshape(M)
@@ -113,9 +109,9 @@ def qmv_pallas(a: jnp.ndarray, v: jnp.ndarray, fmt_params: jnp.ndarray,
 def qmatmul_pallas(a: jnp.ndarray, b: jnp.ndarray, fmt_params: jnp.ndarray,
                    *, bm: int = DEFAULT_BM, bn: int = DEFAULT_BN,
                    bk: int = DEFAULT_BK,
-                   interpret: bool = True) -> jnp.ndarray:
+                   interpret: bool = False) -> jnp.ndarray:
     """a: (M, K) f32, b: (K, N) f32 — M/N/K padded to block multiples by
-    ops.qmatmul_op. fmt_params: int32[5]."""
+    ops.qmatmul_op. fmt_params: int32[1, 5]."""
     M, K = a.shape
     K2, N = b.shape
     assert K == K2 and M % bm == 0 and N % bn == 0 and K % bk == 0
@@ -124,12 +120,13 @@ def qmatmul_pallas(a: jnp.ndarray, b: jnp.ndarray, fmt_params: jnp.ndarray,
         _qmatmul_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
-            pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
+            fmt_spec(fmt_params.shape[-1]),
+            block_spec((bm, bk), lambda i, j, k: (i, k)),
+            block_spec((bk, bn), lambda i, j, k: (k, j)),
         ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
+        out_specs=block_spec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        name="qmatmul",
         interpret=interpret,
     )(fmt_params, a, b)

@@ -3,6 +3,7 @@ optionally chop the output — identical semantics to the fused kernel."""
 from __future__ import annotations
 
 import jax.numpy as jnp
+from jax import lax
 
 from repro.precision import chop, fma_barrier, tree_sum
 
@@ -63,7 +64,8 @@ def qgemm_ref(a: jnp.ndarray, b: jnp.ndarray, fmt_id,
     if chop_inputs:
         ap = chop(ap, fmt_id)
         bp = chop(bp, fmt_id)
-    out = jnp.dot(ap, bp, preferred_element_type=a.dtype)
+    out = jnp.dot(ap, bp, preferred_element_type=a.dtype,
+                  precision=lax.Precision.HIGHEST)
     if chop_out:
         out = chop(out, fmt_id)
     return out
@@ -73,7 +75,8 @@ def qmatmul_ref(a: jnp.ndarray, b: jnp.ndarray, fmt_id,
                 chop_out: bool = True) -> jnp.ndarray:
     a32 = chop(a.astype(jnp.float32), fmt_id)
     b32 = chop(b.astype(jnp.float32), fmt_id)
-    out = jnp.dot(a32, b32, preferred_element_type=jnp.float32)
+    out = jnp.dot(a32, b32, preferred_element_type=jnp.float32,
+                  precision=lax.Precision.HIGHEST)
     if chop_out:
         out = chop(out, fmt_id)
     return out
@@ -90,7 +93,8 @@ def qmatmul_ref_blocked(a: jnp.ndarray, b: jnp.ndarray, fmt_id, bk: int,
     acc = jnp.zeros((a.shape[0], b.shape[1]), jnp.float32)
     for k0 in range(0, K, bk):
         acc = acc + jnp.dot(a32[:, k0:k0 + bk], b32[k0:k0 + bk, :],
-                            preferred_element_type=jnp.float32)
+                            preferred_element_type=jnp.float32,
+                            precision=lax.Precision.HIGHEST)
     if chop_out:
         acc = chop(acc, fmt_id)
     return acc

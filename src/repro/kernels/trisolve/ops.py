@@ -1,7 +1,6 @@
 """Jitted public wrapper for the trisolve kernel: padding + SMEM params."""
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from repro.kernels.chop.ops import make_fmt_params
@@ -12,7 +11,7 @@ from .trisolve import MAX_N, trisolve_pallas
 
 def trisolve_op(Lu: jnp.ndarray, b: jnp.ndarray, fmt_id, *,
                 lower: bool, block: int = 128,
-                interpret: bool | None = None) -> jnp.ndarray:
+                interpret: bool = False) -> jnp.ndarray:
     """Blocked triangular solve on the combined LU matrix, f32 carrier.
 
     Identity-pads n to the block multiple shared with `ref.trisolve_ref`
@@ -20,10 +19,9 @@ def trisolve_op(Lu: jnp.ndarray, b: jnp.ndarray, fmt_id, *,
     contract, DESIGN.md §6.2) and runs the single-launch kernel. Systems
     larger than `trisolve.MAX_N` exceed the whole-matrix VMEM budget and
     route to the bit-identical oracle — a pure perf choice, like the
-    pallas backend's `chop_min_elems` routing.
+    pallas backend's `chop_min_elems` routing. `interpret=True` runs the
+    Pallas interpreter (CPU tests).
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     if Lu.dtype != jnp.float32 or b.dtype != jnp.float32:
         raise TypeError("trisolve_op targets the f32 TPU carrier; got "
                         f"{Lu.dtype} x {b.dtype}")

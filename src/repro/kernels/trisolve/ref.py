@@ -1,13 +1,10 @@
-"""Pure-jnp oracle for kernels/trisolve — and the shared computational core.
+"""Pure-jnp oracle for kernels/trisolve.
 
-`_trisolve_core` is the single source of truth for the blocked
-substitution semantics: the Pallas kernel body (`trisolve.trisolve_pallas`)
-executes this exact function on its VMEM-resident blocks, and the jnp
+`_trisolve_core` defines the blocked substitution semantics; the jnp
 oracle (`trisolve_ref`, the `JnpBackend.chop_trisolve` implementation)
-executes it directly. Sharing the traced ops — not just the reduction
-*shape* — is what makes the two backends bit-identical by construction
-(DESIGN.md §6.2), the same way `precision.chop._chop_core` is shared by
-the chop kernel and its oracle.
+executes it directly. The Pallas kernel (`trisolve._trisolve_kernel`)
+performs the same elementwise ops in the same order on its VMEM-resident
+refs, which keeps the two backends bit-identical (DESIGN.md §6.2).
 
 Blocked semantics (DESIGN.md §6.4): for block row i,
 

@@ -18,11 +18,13 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"mesh {shape} needs {n} devices, have {len(devices)} — run "
             "under XLA_FLAGS=--xla_force_host_platform_device_count=512 "
             "(launch/dryrun.py sets this)")
-    return jax.make_mesh(shape, axes, devices=devices[:n])
+    return jax.make_mesh(shape, axes, devices=devices[:n],
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(shape))
 
 
 def make_host_mesh(model_parallel: int = 1):
     """Small mesh over the real local devices (tests/examples)."""
     n = len(jax.devices())
     mp = max(1, min(model_parallel, n))
-    return jax.make_mesh((n // mp, mp), ("data", "model"))
+    return jax.make_mesh((n // mp, mp), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)

@@ -10,11 +10,11 @@ blocked LU trailing update), and a blocked triangular substitution
   * ``"jnp"``   — the pure-jnp oracle (`repro.precision.chop`), valid on
     any float carrier (f64 for the paper's host experiments);
   * ``"pallas"``— the Pallas TPU kernels (`kernels/chop`,
-    `kernels/qmatmul`, `kernels/trisolve`), f32 carrier, VMEM-resident
-    rounding with no extra HBM round trips. Off-TPU, selecting ``"pallas"`` falls back
-    to ``"jnp"`` (the interpreter is a correctness tool, not a fast
-    path); ``"pallas-interpret"`` forces the kernels through the Pallas
-    interpreter for CPU bit-exactness testing.
+    `kernels/qmatmul`, `kernels/trisolve`), compiled, f32 carrier,
+    VMEM-resident rounding with no extra HBM round trips. It exists only
+    on a TPU: naming it elsewhere raises. ``"pallas-interpret"`` runs
+    the same kernels through the Pallas interpreter — the CPU tests'
+    bit-exactness tool, never a serving path.
 
 Backends are small frozen dataclasses, so they hash by value and can be
 passed as **static jit arguments**: the solvers compile once per
@@ -27,18 +27,20 @@ backends produce bit-identical results for `chop` (same integer RNE
 algorithm elementwise), `chop_mv` (shared lane-padded row-sum reduction
 shape), `chop_matmul` (shared lane-padded K and a single-K-block dot,
 whose reduction is M/N-tile-invariant — measured), and `chop_trisolve`
-(the kernel body and the oracle are the same `_trisolve_core`
-function). The multi-K-tile MXU schedule lives on as
+(the kernel performs the oracle `_trisolve_core`'s elementwise ops in
+the same order). The multi-K-tile MXU schedule lives on as
 `kernels/qmatmul.qmatmul_op` outside the backend contract.
 
 Selection order: explicit argument > `set_default_backend` >
-``REPRO_PRECISION_BACKEND`` env var > ``"jnp"``.
+``REPRO_PRECISION_BACKEND`` env var > the platform: ``"pallas"`` in a
+TPU process, ``"jnp"`` elsewhere. A TPU runs no f64 carrier
+(`precision.chop` refuses it while tracing), so the jnp backend serves
+there only on an f32 carrier.
 """
 from __future__ import annotations
 
 import dataclasses
 import os
-import warnings
 from typing import Callable, Dict, Optional, Union
 
 import jax
@@ -131,16 +133,18 @@ class JnpBackend(PrecisionBackend):
 @dataclasses.dataclass(frozen=True)
 class PallasBackend(PrecisionBackend):
     """Pallas TPU fast path: `kernels/chop` for standalone roundings,
-    `kernels/qmatmul` for the fused matvec/matmul. f32 carrier only —
-    solver entry points coerce operands via `carrier_dtype`.
+    `kernels/qmatmul` for the fused matvec/matmul, `kernels/trisolve`
+    for the blocked substitutions. f32 carrier only — solver entry
+    points coerce operands via `carrier_dtype`.
 
-    `interpret=None` auto-selects the Pallas interpreter off-TPU (the
-    compiled path on TPU); `chop_min_elems` routes small glue arrays to
-    the bit-identical jnp chop to avoid kernel launch overhead."""
+    The kernels are compiled for the TPU; `interpret=True` runs them
+    through the Pallas interpreter instead (CPU tests). `chop_min_elems`
+    routes small glue arrays to the bit-identical jnp chop to avoid
+    kernel launch overhead."""
 
     name: str = dataclasses.field(default="pallas", init=False)
     carrier_dtype: Optional[str] = "float32"
-    interpret: Optional[bool] = None
+    interpret: bool = False
     chop_min_elems: int = DEFAULT_CHOP_MIN_ELEMS
 
     def chop(self, x, fmt_id):
@@ -198,7 +202,6 @@ _REGISTRY: Dict[str, Callable[[], PrecisionBackend]] = {
     "pallas-interpret": lambda: PallasBackend(interpret=True),
 }
 _DEFAULT: Optional[PrecisionBackend] = None
-_WARNED_FALLBACK = False
 
 
 def register_backend(name: str,
@@ -212,40 +215,22 @@ def available_backends():
 
 
 def _from_name(name: str) -> PrecisionBackend:
-    global _WARNED_FALLBACK
     if name not in _REGISTRY:
         raise KeyError(f"unknown precision backend {name!r}; "
                        f"available: {available_backends()}")
     backend = _REGISTRY[name]()
-    if (name == "pallas" and backend.interpret is None
+    if (isinstance(backend, PallasBackend) and not backend.interpret
             and jax.default_backend() != "tpu"):
-        # Fast path requested without TPU hardware: interpret mode would
-        # be orders of magnitude slower than jnp, so serve jnp instead.
-        # The silent downgrade is exactly what a dashboard must see, so
-        # count it (fail-open) in the default metrics registry.
-        try:
-            from repro.obs.metrics import default_registry
-            default_registry().counter(
-                "repro_backend_fallbacks_total",
-                "Precision-backend downgrades (requested backend "
-                "unavailable on this host).",
-                ("requested", "served")).labels(
-                    requested="pallas", served="jnp").inc()
-        except Exception:
-            pass
-        if not _WARNED_FALLBACK:
-            warnings.warn(
-                "precision backend 'pallas' requested off-TPU; falling "
-                "back to 'jnp' (use 'pallas-interpret' to force the "
-                "Pallas interpreter, e.g. for bit-exactness tests)",
-                stacklevel=3)
-            _WARNED_FALLBACK = True
-        return _REGISTRY["jnp"]()
+        raise RuntimeError(
+            f"precision backend {name!r} compiles its Pallas kernels for "
+            f"a TPU, and this process runs on {jax.default_backend()!r}; "
+            "name 'pallas-interpret' to run the kernels through the "
+            "Pallas interpreter (tests), or 'jnp'")
     return backend
 
 
 def set_default_backend(backend: BackendLike) -> Optional[PrecisionBackend]:
-    """Set the process-wide default backend (None restores env/'jnp'
+    """Set the process-wide default backend (None restores env/platform
     resolution). Returns the previous override, for save/restore."""
     global _DEFAULT
     prev = _DEFAULT
@@ -257,7 +242,9 @@ def set_default_backend(backend: BackendLike) -> Optional[PrecisionBackend]:
 def default_backend() -> PrecisionBackend:
     if _DEFAULT is not None:
         return _DEFAULT
-    return _from_name(os.environ.get(ENV_VAR, "jnp"))
+    name = os.environ.get(ENV_VAR) or (
+        "pallas" if jax.default_backend() == "tpu" else "jnp")
+    return _from_name(name)
 
 
 def resolve_backend(backend: BackendLike = None) -> PrecisionBackend:

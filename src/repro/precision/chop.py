@@ -61,20 +61,43 @@ FMT_XMAX_BITS64 = np.array(
     dtype=np.uint64)
 
 
+def _carrier(dtype):
+    """(uint dtype, word bits, mantissa bits, bias, max exponent field)
+    of a carrier dtype. A TPU has no f64 carrier: rounding one bitcasts
+    f64 to u64, which the TPU compiler does not implement, so it is
+    refused here, while tracing, instead of failing in the compiler."""
+    if dtype not in _CARRIERS:
+        raise TypeError(f"unsupported carrier dtype {dtype}")
+    if dtype == jnp.dtype(jnp.float64) and jax.default_backend() == "tpu":
+        raise TypeError(
+            "the f64 carrier does not run on a TPU (its rounding bitcasts "
+            "f64 to u64, which the TPU compiler does not implement); serve "
+            "the f32 carrier: the 'pallas' backend, which is the TPU "
+            "default, or JnpBackend(carrier_dtype='float32')")
+    return _CARRIERS[dtype]
+
+
 def _chop_core(x: jnp.ndarray, t, emin, emax, xmax_bits, saturate) -> jnp.ndarray:
     """Elementwise round-to-format on the carrier's bit patterns.
 
     t/emin/emax are python ints or traced int32 scalars; xmax_bits is the bit
     pattern of the format's xmax in the carrier's uint type; saturate is
-    bool-like."""
+    bool-like.
+
+    Every constant carries an explicit dtype (numpy scalars, never Python
+    literals): this body also runs inside the Pallas kernels, where a
+    weakly typed literal becomes a 64-bit integer under x64 and Mosaic
+    cannot lower it."""
     dtype = x.dtype
-    if dtype not in _CARRIERS:
-        raise TypeError(f"unsupported carrier dtype {dtype}")
-    UINT, W, MBITS, BIAS, EFMAX = _CARRIERS[dtype]
-    one = jnp.asarray(1, UINT)
-    sign_mask = one << (W - 1)
-    frac_mask = (one << MBITS) - 1
-    inf_bits = jnp.asarray(EFMAX, UINT) << MBITS
+    UINT, W, MBITS, BIAS, EFMAX = _carrier(dtype)
+    u = np.dtype(UINT).type
+    i32 = np.int32
+    one = u(1)
+    u_zero = u(0)
+    sign_mask = u(1 << (W - 1))
+    frac_mask = u((1 << MBITS) - 1)
+    inf_bits = u(EFMAX << MBITS)
+    w_max = i32(W - 1)
 
     t = jnp.asarray(t, jnp.int32)
     emin = jnp.asarray(emin, jnp.int32)
@@ -83,51 +106,52 @@ def _chop_core(x: jnp.ndarray, t, emin, emax, xmax_bits, saturate) -> jnp.ndarra
     bits = lax.bitcast_convert_type(x, UINT)
     sign = bits & sign_mask
     mag = bits & ~sign_mask
-    E = (mag >> MBITS).astype(jnp.int32)
+    E = (mag >> u(MBITS)).astype(jnp.int32)
     frac = mag & frac_mask
 
-    special = E == EFMAX          # inf / nan
-    zero = mag == 0
-    is_sub = E == 0
+    special = E == i32(EFMAX)     # inf / nan
+    zero = mag == u_zero
+    is_sub = E == i32(0)
 
-    M = jnp.where(is_sub, frac, frac | (one << MBITS))
-    Eeff = jnp.where(is_sub, 1, E)
-    base = Eeff - (BIAS + MBITS)                       # |x| = M * 2^base
-    Mg = jnp.where(M == 0, one, M)                     # guard clz for zeros
-    msb = (W - 1) - lax.clz(Mg).astype(jnp.int32)
+    M = jnp.where(is_sub, frac, frac | u(1 << MBITS))
+    Eeff = jnp.where(is_sub, i32(1), E)
+    base = Eeff - i32(BIAS + MBITS)                    # |x| = M * 2^base
+    Mg = jnp.where(M == u_zero, one, M)                # guard clz for zeros
+    msb = w_max - lax.clz(Mg).astype(jnp.int32)
     e_x = msb + base
 
-    q = jnp.maximum(e_x, emin) - (t - 1)
+    q = jnp.maximum(e_x, emin) - (t - i32(1))
     s = q - base                                       # bits to round off
-    sc = jnp.clip(s, 0, W - 1).astype(UINT)
-    scm1 = jnp.clip(s - 1, 0, W - 1).astype(UINT)
+    sc = jnp.clip(s, i32(0), w_max).astype(UINT)
+    scm1 = jnp.clip(s - i32(1), i32(0), w_max).astype(UINT)
     lsb = (Mg >> sc) & one
-    round_add = jnp.where(s > 0, ((one << scm1) - 1) + lsb, 0)
+    round_add = jnp.where(s > i32(0), ((one << scm1) - one) + lsb, u_zero)
     Mr = (Mg + round_add) >> sc
     # Full underflow: s >= W would be clipped by sc; |x| < 2^(q-1) there, so
     # the correctly-rounded result is zero.
-    Mr = jnp.where(s > W - 1, jnp.zeros((), UINT), Mr)
-    exact = s <= 0                                     # already representable
+    Mr = jnp.where(s > w_max, u_zero, Mr)
+    exact = s <= i32(0)                                # already representable
 
     # --- reassemble Mr * 2^q into carrier bits -----------------------------
-    zero_r = Mr == 0
+    zero_r = Mr == u_zero
     Mr_g = jnp.where(zero_r, one, Mr)
-    msb_r = (W - 1) - lax.clz(Mr_g).astype(jnp.int32)
+    msb_r = w_max - lax.clz(Mr_g).astype(jnp.int32)
     new_e = msb_r + q
     emin_car = 1 - BIAS
-    sub_res = new_e < emin_car
+    sub_res = new_e < i32(emin_car)
 
-    shift_n = MBITS - msb_r                            # in [-1, MBITS]
-    left = jnp.clip(shift_n, 0, W - 1).astype(UINT)
-    right = jnp.clip(-shift_n, 0, W - 1).astype(UINT)
+    shift_n = i32(MBITS) - msb_r                       # in [-1, MBITS]
+    left = jnp.clip(shift_n, i32(0), w_max).astype(UINT)
+    right = jnp.clip(-shift_n, i32(0), w_max).astype(UINT)
     frac_n = ((Mr_g << left) >> right) & frac_mask
-    bits_n = ((new_e + BIAS).astype(UINT) << MBITS) | frac_n
+    bits_n = ((new_e + i32(BIAS)).astype(UINT) << u(MBITS)) | frac_n
 
-    k_sub = jnp.clip(q - (emin_car - MBITS), 0, W - 1).astype(UINT)
+    k_sub = jnp.clip(q - i32(emin_car - MBITS), i32(0),
+                     w_max).astype(UINT)
     bits_s = Mr_g << k_sub                             # exponent field 0
 
     out_mag = jnp.where(sub_res, bits_s, bits_n)
-    out_mag = jnp.where(zero_r, jnp.zeros((), UINT), out_mag)
+    out_mag = jnp.where(zero_r, u_zero, out_mag)
 
     over = out_mag > xmax_bits
     sat_mag = jnp.where(jnp.asarray(saturate, bool), xmax_bits, inf_bits)
@@ -157,16 +181,15 @@ def fma_barrier(x: jnp.ndarray) -> jnp.ndarray:
     disagreed in the final residual only under jit).
     """
     x = jnp.asarray(x)
-    if x.dtype not in _CARRIERS:
-        raise TypeError(f"unsupported carrier dtype {x.dtype}")
-    _, _, MBITS, _, _ = _CARRIERS[x.dtype]
+    _, _, MBITS, _, _ = _carrier(x.dtype)
     f = get_format("fp64" if x.dtype == jnp.dtype(jnp.float64) else "fp32")
     assert f.t == MBITS + 1     # carrier-exact: rounding is the identity
     return _chop_core(x, f.t, f.emin, f.emax, _fmt_xmax_bits(f, x.dtype),
                       False)
 
 
-def tree_sum(x: jnp.ndarray, axis: int = -1) -> jnp.ndarray:
+def tree_sum(x: jnp.ndarray, axis: int = -1,
+             keepdims: bool = False) -> jnp.ndarray:
     """Sum along `axis` with a FIXED pairwise reduction tree.
 
     `jnp.sum` lowers to an XLA reduce whose accumulation order is
@@ -181,22 +204,26 @@ def tree_sum(x: jnp.ndarray, axis: int = -1) -> jnp.ndarray:
     `concatenate`, deliberately, since this also runs inside the Pallas
     qmv kernel body and sub-lane concatenates are a Mosaic lowering
     risk. Every unrounded carrier reduction on the solver hot path goes
-    through this (DESIGN.md §6.2, §7.3)."""
+    through this (DESIGN.md §6.2, §7.3).
+
+    `keepdims=True` leaves the reduced axis as a size-1 axis in place —
+    the same adds in the same order, in the layout a kernel stores."""
     x = jnp.asarray(x)
     axis = axis % x.ndim
     x = jnp.moveaxis(x, axis, -1)
     if x.shape[-1] == 0:
-        return jnp.zeros(x.shape[:-1], x.dtype)
-    tail = None
-    while x.shape[-1] > 1:
-        n = x.shape[-1]
-        m = n // 2
-        if n % 2:
-            last = x[..., n - 1]
-            tail = last if tail is None else tail + last
-        x = x[..., :m] + x[..., m:2 * m]
-    out = x[..., 0]
-    return out if tail is None else out + tail
+        out = jnp.zeros(x.shape[:-1] + (1,), x.dtype)
+    else:
+        tail = None
+        while x.shape[-1] > 1:
+            n = x.shape[-1]
+            m = n // 2
+            if n % 2:
+                last = x[..., n - 1:n]
+                tail = last if tail is None else tail + last
+            x = x[..., :m] + x[..., m:2 * m]
+        out = x if tail is None else x + tail
+    return jnp.moveaxis(out, -1, axis) if keepdims else out[..., 0]
 
 
 def _fmt_xmax_bits(f: FloatFormat, dtype) -> int:
@@ -332,7 +359,7 @@ def chop_matmul(a: jnp.ndarray, b: jnp.ndarray, fmt_id,
     if chop_inputs:
         a = chop(a, fmt_id)
         b = chop(b, fmt_id)
-    out = a @ b
+    out = jnp.matmul(a, b, precision=lax.Precision.HIGHEST)
     if chop_output:
         out = chop(out, fmt_id)
     return out

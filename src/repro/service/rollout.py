@@ -137,8 +137,7 @@ class ShadowServer:
                  obs=None,
                  decision_log_path: Optional[str] = None,
                  warmup: Optional[str] = None,
-                 warmup_buckets: Optional[List[int]] = None,
-                 compile_cache_dir: Optional[str] = None):
+                 warmup_buckets: Optional[List[int]] = None):
         self.registry = registry
         self.rollout_cfg = rollout_cfg
         self.clock = clock
@@ -156,8 +155,7 @@ class ShadowServer:
             registry, task=task, reward_cfg=reward_cfg,
             batcher_cfg=batcher_cfg, online_cfg=online_cfg, clock=clock,
             seed=seed, executor=executor, obs=obs, warmup=warmup,
-            warmup_buckets=warmup_buckets,
-            compile_cache_dir=compile_cache_dir)
+            warmup_buckets=warmup_buckets)
         self.candidate: Optional[AutotuneServer] = None
         self.phase = "idle"       # idle|canary|promoted|rolled_back
         self.candidate_version: Optional[str] = None

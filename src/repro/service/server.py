@@ -118,7 +118,6 @@ class AutotuneServer:
                  breaker_cfg: BreakerConfig = BreakerConfig(),
                  warmup: Optional[str] = None,
                  warmup_buckets: Optional[List[int]] = None,
-                 compile_cache_dir: Optional[str] = None,
                  warmup_pace: Optional[Callable] = None):
         if isinstance(registry, PolicyRegistry):
             self.registry: Optional[PolicyRegistry] = registry
@@ -211,13 +210,13 @@ class AutotuneServer:
         # order (the order Q-updates were applied) — push-style consumers.
         self.on_response: Optional[Callable[[SolveResponse], None]] = None
         # Compile-cliff controls (DESIGN.md §12): persistent compile
-        # cache (env-driven; no-op when neither the kwarg nor
-        # REPRO_COMPILE_CACHE_DIR is set) + optional AOT warmup of the
-        # executable grid. `warm_buckets` feeds the readiness gate — a
-        # bucket is warm once it has either flushed a live batch or
-        # been AOT-precompiled; with a warmup grid configured, /readyz
-        # holds at 503 until the whole expected grid is warm.
-        aot.enable_persistent_cache(compile_cache_dir)
+        # cache (JAX_COMPILATION_CACHE_DIR, else <checkout>/.cache/xla)
+        # + optional AOT warmup of the executable grid. `warm_buckets`
+        # feeds the readiness gate — a bucket is warm once it has either
+        # flushed a live batch or been AOT-precompiled; with a warmup
+        # grid configured, /readyz holds at 503 until the whole expected
+        # grid is warm.
+        aot.enable_persistent_cache()
         self.warm_buckets: set = set()
         self.warm_order: List[int] = []
         self.warmup = None

@@ -157,7 +157,8 @@ def lu_factor_blocked(A: jnp.ndarray, fmt_id, block: int = 64,
         # (shared trace on every backend: plain jnp + bit-exact chop).
         def tri_row(i, U12):
             lrow = lax.dynamic_slice(Lpan, (i, 0), (1, block))
-            acc = chop(lrow @ U12, fmt_id)
+            acc = chop(jnp.dot(lrow, U12,
+                               precision=lax.Precision.HIGHEST), fmt_id)
             new = chop(lax.dynamic_slice(A12, (i, 0), (1, m)) - acc,
                        fmt_id)
             return lax.dynamic_update_slice(U12, new, (i, 0))

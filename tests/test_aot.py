@@ -8,7 +8,7 @@ compile-free, ``warmup="background"`` must flip the `/readyz` warm gate
 per bucket in priority order while traffic is already flowing.
 
 The persistent-cache contract — a restarted server rebuilds its grid
-from ``REPRO_COMPILE_CACHE_DIR`` with ZERO fresh XLA compiles — runs as
+from ``JAX_COMPILATION_CACHE_DIR`` with ZERO fresh XLA compiles — runs as
 two subprocess boots sharing one cache directory, asserted on the
 jax compilation-cache hit/miss counters (never on wall time).
 """
@@ -110,9 +110,15 @@ def test_plan_enumerates_tasks_per_bucket_in_priority_order():
 
 @pytest.mark.fast
 def test_enable_persistent_cache_noop_without_dir(monkeypatch):
+    """Without JAX_COMPILATION_CACHE_DIR the cache sits at the fixed
+    <checkout>/.cache/xla — never a temporary, per-process or per-run
+    path — and enabling it again changes nothing."""
     monkeypatch.delenv(aot.ENV_CACHE_DIR, raising=False)
-    # No kwarg, no env: nothing changes (returns whatever is in force).
-    assert aot.enable_persistent_cache() == aot.cache_stats()["dir"]
+    checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    want = os.path.join(checkout, ".cache", "xla")
+    assert aot.default_cache_dir() == want
+    assert aot.enable_persistent_cache() == want
+    assert aot.enable_persistent_cache() == aot.cache_stats()["dir"] == want
 
 
 # ---------------------------------------------------------------------------
@@ -292,13 +298,13 @@ print("RESULT " + json.dumps({
 
 
 def test_warm_restart_zero_fresh_xla_compiles(tmp_path):
-    """Two boots sharing one REPRO_COMPILE_CACHE_DIR: the restart must
+    """Two boots sharing one JAX_COMPILATION_CACHE_DIR: the restart must
     rebuild its grid purely from disk — zero compile-cache misses,
     asserted on counters, never timing — and solve bit-identically."""
     env = dict(os.environ,
                PYTHONPATH=SRC + os.pathsep + os.environ.get("PYTHONPATH",
                                                             ""))
-    env["REPRO_COMPILE_CACHE_DIR"] = str(tmp_path / "xla-cache")
+    env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "xla-cache")
     runs = []
     for _ in range(2):
         out = subprocess.run([sys.executable, "-c", WARM_BOOT], env=env,

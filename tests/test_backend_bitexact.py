@@ -337,11 +337,49 @@ def test_serving_stack_bitexact(tmp_path):
 
 @pytest.mark.fast
 def test_pallas_falls_back_to_jnp_off_tpu():
+    """There is no fallback: off a TPU, naming the compiled 'pallas'
+    backend raises and says how to get the interpreter, and a process
+    that names no backend resolves to 'jnp'."""
     import jax
     if jax.default_backend() == "tpu":
         pytest.skip("on TPU the pallas backend is served compiled")
-    assert resolve_backend("pallas").name == "jnp"
+    with pytest.raises(RuntimeError, match="pallas-interpret"):
+        resolve_backend("pallas")
     assert resolve_backend("pallas-interpret").name == "pallas"
+    assert resolve_backend("pallas-interpret").interpret
+    assert resolve_backend(None).name == "jnp"
+
+
+@pytest.mark.fast
+def test_tpu_process_defaults_to_compiled_pallas(monkeypatch):
+    """Where no backend is named, the platform decides: a TPU process
+    serves the compiled pallas kernels (never the interpreter)."""
+    import jax
+
+    from repro.precision import backend as B
+    monkeypatch.delenv(B.ENV_VAR, raising=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    bk = resolve_backend(None)
+    assert isinstance(bk, PallasBackend) and not bk.interpret
+    assert resolve_backend("pallas") == bk
+
+
+@pytest.mark.fast
+def test_f64_carrier_refused_on_tpu(monkeypatch):
+    """A TPU cannot bitcast f64 to u64: rounding on an f64 carrier is
+    refused while tracing, with the reason, before any compile; the f32
+    carrier is untouched."""
+    import jax
+
+    from repro.precision import chop
+    x64, x32 = jnp.ones(4, jnp.float64), jnp.ones(4, jnp.float32)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(TypeError, match="f64 carrier does not run on a TPU"):
+        chop(x64, FORMAT_ID["bf16"])
+    with pytest.raises(TypeError, match="f64 carrier does not run on a TPU"):
+        jax.jit(lambda v: chop(v, FORMAT_ID["fp32"])).lower(x64)
+    np.testing.assert_array_equal(np.asarray(chop(x32, FORMAT_ID["bf16"])),
+                                  np.ones(4, np.float32))
 
 
 @pytest.mark.fast

@@ -146,7 +146,7 @@ def test_trisolve_kernel_matches_oracle(fid, n, block, lower):
     rng = np.random.default_rng(10 * n + fid)
     Lu = jnp.asarray(tri_factors(n, rng), jnp.float32)
     b = jnp.asarray(rng.standard_normal(n), jnp.float32)
-    got = trisolve_op(Lu, b, fid, lower=lower, block=block)
+    got = trisolve_op(Lu, b, fid, lower=lower, block=block, interpret=True)
     want = trisolve_ref(Lu, b, fid, lower=lower, block=block)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
@@ -158,13 +158,14 @@ def test_trisolve_kernel_matches_oracle_batched(lower):
                       jnp.float32)
     bs = jnp.asarray(rng.standard_normal((3, 40)), jnp.float32)
     got = jax.vmap(lambda L, b: trisolve_op(L, b, BF16, lower=lower,
-                                            block=16))(Lus, bs)
+                                            block=16, interpret=True))(Lus, bs)
     want = jax.vmap(lambda L, b: trisolve_ref(L, b, BF16, lower=lower,
                                               block=16))(Lus, bs)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
     # ... and batched == single (row-independent solves).
     for i in range(3):
-        single = trisolve_op(Lus[i], bs[i], BF16, lower=lower, block=16)
+        single = trisolve_op(Lus[i], bs[i], BF16, lower=lower, block=16,
+                             interpret=True)
         np.testing.assert_array_equal(np.asarray(single),
                                       np.asarray(got)[i])
 
@@ -232,7 +233,7 @@ def test_chop_matmul_bitexact_batched():
     rng = np.random.default_rng(11)
     a = jnp.asarray(rng.standard_normal((3, 48, 16)), jnp.float32)
     b = jnp.asarray(rng.standard_normal((3, 16, 48)), jnp.float32)
-    got = jax.vmap(lambda x, y: qgemm_op(x, y, BF16))(a, b)
+    got = jax.vmap(lambda x, y: qgemm_op(x, y, BF16, interpret=True))(a, b)
     want = jax.vmap(lambda x, y: qgemm_ref(x, y, BF16))(a, b)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 

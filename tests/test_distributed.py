@@ -84,7 +84,8 @@ from repro.configs.base import ShapeConfig
 
 cfg = get_smoke("jamba-v0.1-52b")   # exercises mamba+attn+MoE together
 mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
-                     devices=jax.devices())
+                     devices=jax.devices(),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 3)
 tcfg = TrainStepConfig(opt=AdamWConfig(quantize_moments=True,
                                        quant_block=16),
                        compute_dtype=jnp.float32)

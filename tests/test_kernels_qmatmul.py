@@ -28,7 +28,8 @@ def test_qmatmul_vs_blocked_ref(shape, fmt):
     M, K, N = shape
     bk = 128
     a, b = _mats(M, K, N)
-    got = np.asarray(qmatmul_op(a, b, FORMAT_ID[fmt], bm=32, bn=128, bk=bk))
+    got = np.asarray(qmatmul_op(a, b, FORMAT_ID[fmt], bm=32, bn=128, bk=bk,
+                                interpret=True))
     Kp = -(-K // bk) * bk
     ap = jnp.pad(a, ((0, 0), (0, Kp - K)))
     bp = jnp.pad(b, ((0, Kp - K), (0, 0)))
@@ -46,7 +47,8 @@ def test_qmatmul_vs_blocked_ref(shape, fmt):
 def test_qmatmul_close_to_mathematical_ref(fmt):
     """Accumulation-order differences stay within ~1 output ulp."""
     a, b = _mats(128, 512, 128)
-    got = np.asarray(qmatmul_op(a, b, FORMAT_ID[fmt], bm=64, bn=128, bk=128))
+    got = np.asarray(qmatmul_op(a, b, FORMAT_ID[fmt], bm=64, bn=128, bk=128,
+                                interpret=True))
     want = np.asarray(qmatmul_ref(a, b, FORMAT_ID[fmt]))
     u = FORMATS[fmt].unit_roundoff
     scale = np.abs(want) + np.sqrt(512)
@@ -58,9 +60,9 @@ def test_qmatmul_emulates_precision_loss():
     a, b = _mats(64, 128, 64)
     exact = np.asarray(a @ b)
     lo = np.asarray(qmatmul_op(a, b, FORMAT_ID["e4m3"], bm=32, bn=128,
-                               bk=128))
+                               bk=128, interpret=True))
     hi = np.asarray(qmatmul_op(a, b, FORMAT_ID["fp32"], bm=32, bn=128,
-                               bk=128))
+                               bk=128, interpret=True))
     err_lo = np.abs(lo - exact).mean()
     err_hi = np.abs(hi - exact).mean()
     assert err_lo > 10 * err_hi
@@ -69,9 +71,11 @@ def test_qmatmul_emulates_precision_loss():
 def test_qmatmul_chop_out_flag():
     a, b = _mats(32, 128, 128)
     with_chop = np.asarray(qmatmul_op(a, b, FORMAT_ID["bf16"],
-                                      chop_out=True, bm=32, bn=128, bk=128))
+                                      chop_out=True, bm=32, bn=128, bk=128,
+                                      interpret=True))
     no_chop = np.asarray(qmatmul_op(a, b, FORMAT_ID["bf16"],
-                                    chop_out=False, bm=32, bn=128, bk=128))
+                                    chop_out=False, bm=32, bn=128, bk=128,
+                                    interpret=True))
     # Unchopped accumulator has values not representable in bf16.
     from repro.precision import chop_static
     assert np.array_equal(

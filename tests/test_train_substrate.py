@@ -173,20 +173,8 @@ def test_sync_leaf_modes(mode, tol):
     from jax.sharding import Mesh, PartitionSpec as P
     mesh = Mesh(np.array(jax.devices()[:1]), ("pod",))
     g = jax.random.normal(KEY, (64,))
-    if hasattr(jax, "shard_map"):          # newer jax; kwarg name varies
-        try:
-            f = jax.shard_map(lambda x: sync_leaf(x, mode),
-                              mesh=mesh, in_specs=P(), out_specs=P(),
-                              check_vma=False)
-        except TypeError:                  # top-level but pre-rename
-            f = jax.shard_map(lambda x: sync_leaf(x, mode),
-                              mesh=mesh, in_specs=P(), out_specs=P(),
-                              check_rep=False)
-    else:                                  # jax 0.4.x
-        from jax.experimental.shard_map import shard_map
-        f = shard_map(lambda x: sync_leaf(x, mode),
-                      mesh=mesh, in_specs=P(), out_specs=P(),
-                      check_rep=False)
+    f = jax.shard_map(lambda x: sync_leaf(x, mode), mesh=mesh,
+                      in_specs=P(), out_specs=P(), check_vma=False)
     out = f(g)
     assert float(jnp.max(jnp.abs(out - g))) <= tol * float(
         jnp.max(jnp.abs(g))) + 1e-6
