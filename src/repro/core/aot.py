@@ -28,6 +28,7 @@ kills the cliff three ways:
 """
 from __future__ import annotations
 
+import contextvars
 import dataclasses
 import json
 import os
@@ -35,6 +36,8 @@ import threading
 import time
 from pathlib import Path
 from typing import Callable, Dict, List, Optional, Sequence
+
+from repro.obs import trace as obs_trace
 
 ENV_CACHE_DIR = "JAX_COMPILATION_CACHE_DIR"
 
@@ -126,10 +129,17 @@ def _install_listener() -> None:
 
 
 def cache_stats() -> dict:
-    """Persistent-cache state: directory in force (None = disabled) and
-    hit/miss event counts since process start."""
+    """Persistent-cache state: directory in force (None = disabled),
+    hit/miss event counts since process start, and the process totals
+    of the executable builds' two timers (`core.executor`): `lower_s`,
+    Python trace plus lowering, and `compile_s`, XLA compile or
+    persistent-cache load."""
+    from repro.core.executor import executor_compile_log
+    log = executor_compile_log()
     return {"dir": _cache_dir, "hits": int(_cache_events["hits"]),
-            "misses": int(_cache_events["misses"])}
+            "misses": int(_cache_events["misses"]),
+            "lower_s": sum(r["lower_s"] for r in log),
+            "compile_s": sum(r["compile_s"] for r in log)}
 
 
 # ---------------------------------------------------------------------------
@@ -235,7 +245,11 @@ def _sweep(entries: Sequence[GridEntry], report: WarmupReport,
         if pace is not None:
             pace(e)
         try:
-            ok = bool(e.task.precompile_bucket(e.bucket, e.chunk))
+            # Parent of the build's aot.lower / aot.compile spans
+            # (core.executor), in the server's tracer when it set one.
+            with obs_trace.span("aot.bucket", cat="aot",
+                                tid=int(e.bucket), **e.labels()):
+                ok = bool(e.task.precompile_bucket(e.bucket, e.chunk))
         except Exception as err:
             # Fail-open by contract: warmup must never take a server
             # down — the cell just compiles lazily on first hit.
@@ -284,6 +298,9 @@ class BackgroundWarmup:
         self.report = WarmupReport(entries=len(self.entries))
         self._on_entry = on_entry
         self._pace = pace
+        # The sweep runs in the constructing context, so it records
+        # into the tracer that was current there.
+        self._context = contextvars.copy_context()
         self._thread = threading.Thread(
             target=self._run, name="repro-aot-warmup", daemon=True)
 
@@ -292,7 +309,8 @@ class BackgroundWarmup:
         return self
 
     def _run(self) -> None:
-        _sweep(self.entries, self.report, self._on_entry, self._pace)
+        self._context.run(_sweep, self.entries, self.report,
+                          self._on_entry, self._pace)
 
     @property
     def done(self) -> bool:

@@ -34,6 +34,7 @@ import numpy as np
 from repro.core.executor import resolve_executor
 from repro.core.task import bucket_of
 from repro.data.matrices import LinearSystem, pad_system
+from repro.obs import trace as obs_trace
 from repro.solvers.ir import IRConfig, gmres_ir_batch_lowerable
 
 __all__ = ["SolveRecord", "bucket_of", "pad_to_bucket",
@@ -94,8 +95,14 @@ def solve_fixed_batch(A_rows: Sequence[np.ndarray],
     from repro.tasks.base import stack_fixed
     ex = resolve_executor(executor)
     bk = resolve_backend(backend)
-    A, b, x, acts, k = stack_fixed(list(zip(A_rows, b_rows, x_rows)),
-                                   action_rows, ex.preferred_chunk(chunk))
+    # Flush-path spans (DESIGN.md §8.4): no-ops unless the server made
+    # its tracer current around this flush.
+    with obs_trace.span("flush.stack"):
+        A, b, x, acts, k = stack_fixed(
+            list(zip(A_rows, b_rows, x_rows)), action_rows,
+            ex.preferred_chunk(chunk))
+    obs_trace.note("flush", input_bytes=int(
+        A.nbytes + b.nbytes + x.nbytes + acts.nbytes))
     # The solver rides as a `LowerableCall`, which both keys the
     # dispatcher memo by computation value — every call site with equal
     # (cfg, backend) shares one executable per shape, across tasks —
@@ -103,4 +110,5 @@ def solve_fixed_batch(A_rows: Sequence[np.ndarray],
     # will run (DESIGN.md §12).
     stats = ex.dispatch(gmres_ir_batch_lowerable(ir_cfg, bk),
                         (A, b, x, acts), A.shape[-1])
-    return records_from_stats(stats, k)
+    with obs_trace.span("flush.fetch"):
+        return records_from_stats(stats, k)

@@ -68,6 +68,8 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro.obs import trace as obs_trace
+
 ENV_VAR = "REPRO_SOLVE_EXECUTOR"
 
 
@@ -143,7 +145,8 @@ def executor_compile_count() -> int:
 
 def executor_compile_log() -> List[dict]:
     """Copies of the per-build records: executor, bucket, rows,
-    backend, seconds."""
+    backend, seconds, and its two parts: lower_s (Python trace plus
+    lowering) and compile_s (XLA compile or persistent-cache load)."""
     with _COMPILE_LOCK:
         return [dict(r) for r in _COMPILE_LOG]
 
@@ -157,12 +160,15 @@ def _backend_label(solve_fn) -> str:
 
 
 def _record_compile(executor_name: str, solve_fn, n_pad: int, rows: int,
-                    seconds: float) -> None:
+                    lower_s: float, compile_s: float) -> None:
+    seconds = lower_s + compile_s
     with _COMPILE_LOCK:
         _COMPILE_LOG.append({"executor": executor_name,
                              "bucket": int(n_pad), "rows": int(rows),
                              "backend": _backend_label(solve_fn),
-                             "seconds": float(seconds)})
+                             "seconds": float(seconds),
+                             "lower_s": float(lower_s),
+                             "compile_s": float(compile_s)})
     # Fail-open against the process-default metrics registry
     # (DESIGN.md §8) — compile accounting must never break a solve.
     try:
@@ -226,7 +232,10 @@ class SolveExecutor:
         from repro import faults
         faults.maybe_raise("executor.dispatch", executor=self.name,
                            n_pad=n_pad)
-        return batch_callable(self, key, solve_fn)(arrays, n_pad)
+        # Placement, carrier coercion and the launch, up to the return
+        # of the call: no sync here, the results stay on the device.
+        with obs_trace.span("flush.dispatch"):
+            return batch_callable(self, key, solve_fn)(arrays, n_pad)
 
     def precompile(self, solve_fn: Callable, arrays: Sequence,
                    n_pad: int, key=None) -> bool:
@@ -405,11 +414,18 @@ class _BatchDispatch:
         with self._lock:
             exe = self.executables.get(key)
             if exe is None:
-                t0 = time.perf_counter()
-                exe = self._lowered(args).compile()
                 rows = int(np.shape(args[0])[0]) if np.ndim(args[0]) else 0
+                labels = dict(cat="aot", bucket=int(n_pad), rows=rows,
+                              executor=self.executor.name)
+                t0 = time.perf_counter()
+                with obs_trace.span("aot.lower", **labels):
+                    lowered = self._lowered(args)
+                t1 = time.perf_counter()
+                with obs_trace.span("aot.compile", **labels):
+                    exe = lowered.compile()
                 _record_compile(self.executor.name, self.solve_fn,
-                                n_pad, rows, time.perf_counter() - t0)
+                                n_pad, rows, t1 - t0,
+                                time.perf_counter() - t1)
                 self.executables[key] = exe
         return exe
 

@@ -10,8 +10,9 @@ Four pieces, all stdlib-only and importable without jax:
     front door (``/metrics``, ``/healthz``, ``/readyz``) on a stdlib
     background thread;
   * `obs.trace`    — per-request spans (submit → queue wait → solve →
-    reward → Q-update) in a bounded ring buffer, dumpable as Chrome
-    trace-event JSON;
+    reward → Q-update) and nested inline spans (the flush path, AOT
+    warmup) in a bounded ring buffer, dumpable as Chrome trace-event
+    JSON, with a hook that mirrors inline spans onto the profiler;
   * `obs.trajlog`  — append-only JSONL trajectory log (features, state,
     action, eps, explore, reward, outcome, policy version) that makes
     off-policy evaluation from logged service streams possible.
@@ -54,6 +55,8 @@ class Observability:
             else default_registry()
         self.tracer = tracer if tracer is not None \
             else Tracer(capacity=trace_capacity)
+        if getattr(self.tracer, "registry", None) is None:   # span faults
+            self.tracer.registry = self.registry
         self.trajlog = (TrajectoryLog(
             trajectory_path, max_bytes=trajectory_max_bytes,
             max_segments=trajectory_max_segments, sync=trajectory_sync)

@@ -35,6 +35,11 @@ import numpy as np
 from repro import faults
 from repro.core.executor import resolve_executor
 from repro.core.task import Outcome, TunableTask, coerce_task
+from repro.obs import trace as obs_trace
+
+# Per-process flush ids: the `flush` span's row and the `flush` arg of
+# each of its requests' `solve` spans (DESIGN.md §8.4).
+_FLUSH_IDS = itertools.count(1)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -74,6 +79,8 @@ class FlushResult:
     t_solve_start: float = 0.0
     t_solve_end: float = 0.0
     solve_s: float = 0.0
+    flush_id: int = 0
+    span_id: Optional[int] = None   # the `flush` span (None: not traced)
 
 
 class MicroBatcher:
@@ -118,27 +125,32 @@ class MicroBatcher:
     def _flush_bucket(self, bucket: int, entries: List[_Pending]
                       ) -> FlushResult:
         target = self.flush_target(bucket)
-        t0, w0 = self.clock(), time.perf_counter()
-        # Fault site: a raise here leaves the entries queued (pump()
-        # only dequeues after a successful flush), so the flush is
-        # retried by the next pump — the supervised HTTP flush loop
-        # counts the restart and carries on.
-        faults.maybe_raise("batcher.flush", bucket=bucket,
-                           n_entries=len(entries))
-        records = self.task.solve_rows(
-            [e.rows for e in entries], [e.action_row for e in entries],
-            target)
-        # Fault site: corrupt solved outcomes (NaN / divergence) after
-        # the real solve — the poisoned-reward path the breaker and
-        # Q-update quarantine defend against.
-        records = [
-            faults.corrupt_outcome("solver.outcome", rec, bucket=bucket,
-                                   action_row=e.action_row)
-            for e, rec in zip(entries, records)]
+        fid = next(_FLUSH_IDS)
+        with obs_trace.span("flush", tid=fid, cat="flush", flush=fid,
+                            bucket=bucket, n_live=len(entries),
+                            n_rows=target) as sid:
+            t0, w0 = self.clock(), time.perf_counter()
+            # Fault site: a raise here leaves the entries queued (pump()
+            # only dequeues after a successful flush), so the flush is
+            # retried by the next pump — the supervised HTTP flush loop
+            # counts the restart and carries on.
+            faults.maybe_raise("batcher.flush", bucket=bucket,
+                               n_entries=len(entries))
+            records = self.task.solve_rows(
+                [e.rows for e in entries], [e.action_row for e in entries],
+                target)
+            # Fault site: corrupt solved outcomes (NaN / divergence)
+            # after the real solve — the poisoned-reward path the
+            # breaker and Q-update quarantine defend against.
+            records = [
+                faults.corrupt_outcome("solver.outcome", rec,
+                                       bucket=bucket,
+                                       action_row=e.action_row)
+                for e, rec in zip(entries, records)]
+            t1, w1 = self.clock(), time.perf_counter()
         return FlushResult(bucket, [e.req_id for e in entries], records,
-                           target, t_solve_start=t0,
-                           t_solve_end=self.clock(),
-                           solve_s=time.perf_counter() - w0)
+                           target, t_solve_start=t0, t_solve_end=t1,
+                           solve_s=w1 - w0, flush_id=fid, span_id=sid)
 
     def expire_overdue(self, now: Optional[float] = None) -> List[_Pending]:
         """Remove and return every queued entry older than

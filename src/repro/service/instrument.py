@@ -145,12 +145,6 @@ class ServiceInstruments:
         lab = dict(task=self.task, bucket=resp.bucket)
         self.responses.labels(**lab).inc()
         self.latency.labels(**lab).observe(resp.latency_s)
-        self.reward_ewma.labels(task=self.task).set(
-            telemetry.reward_ewma.value)
-        self.abs_rpe_ewma.labels(task=self.task).set(
-            telemetry.abs_rpe_ewma.value)
-        self.policy_info.labels(task=self.task,
-                                version=resp.policy_version).set(1)
         rid = resp.request_id
         t_sub, t_done = info.submitted_at, info.submitted_at + resp.latency_s
         tracer = self.obs.tracer
@@ -165,7 +159,8 @@ class ServiceInstruments:
                             tid=rid)
             tracer.add_span("solve", flush.t_solve_start,
                             flush.t_solve_end, tid=rid,
-                            bucket=resp.bucket, n_rows=flush.n_rows)
+                            bucket=resp.bucket, n_rows=flush.n_rows,
+                            flush=flush.flush_id)
             tracer.add_span("reward", flush.t_solve_end, t_reward,
                             tid=rid)
         tracer.add_span("q_update", t_reward, t_update, tid=rid,
@@ -196,6 +191,17 @@ class ServiceInstruments:
                 "seq": int(resp.seq),
                 "quarantined": bool(resp.quarantined),
             })
+
+    @fail_open
+    def on_telemetry(self, telemetry, version: str) -> None:
+        """The Telemetry EWMAs and the live policy version, once per
+        flush (end of `flush.complete`) and after expiries: the values a
+        scrape reads, without a gauge write per request."""
+        self.reward_ewma.labels(task=self.task).set(
+            telemetry.reward_ewma.value)
+        self.abs_rpe_ewma.labels(task=self.task).set(
+            telemetry.abs_rpe_ewma.value)
+        self.policy_info.labels(task=self.task, version=version).set(1)
 
     # -- fault tolerance ---------------------------------------------------
     @fail_open

@@ -47,6 +47,7 @@ from repro.core.policy import PrecisionPolicy
 from repro.core.rewards import RewardConfig
 from repro.core.task import FAILED, Outcome, coerce_task
 from repro.obs import Observability
+from repro.obs import trace as obs_trace
 from repro.service.batcher import BatcherConfig, MicroBatcher
 from repro.service.breaker import CLOSED, BreakerConfig, CircuitBreakers
 from repro.service.instrument import ServiceInstruments
@@ -166,6 +167,13 @@ class AutotuneServer:
             self.obs = Observability()
         else:
             self.obs = obs
+        # Inline spans (the flush path, AOT warmup) also become host
+        # events on the profiler's clock, the one device ops use.
+        self._tracer = self.obs.tracer if self.obs is not None else None
+        if self._tracer is not None \
+                and getattr(self._tracer, "annotate", None) is None:
+            import jax
+            self._tracer.annotate = jax.profiler.TraceAnnotation
         self.engine = AutotuneEngine(self.task, reward_cfg,
                                      policy=self.live, seed=seed)
         self.learner = OnlineLearner(self.engine, online_cfg,
@@ -232,13 +240,14 @@ class AutotuneServer:
                 batcher_cfg.max_batch,
                 trajectory_path=getattr(trajlog, "path", None))
             self._warmup_expected = frozenset(e.bucket for e in entries)
-            if warmup == "sync":
-                self.warmup = aot.precompile(entries,
-                                             on_entry=self._on_warm)
-            else:
-                self.warmup = aot.BackgroundWarmup(
-                    entries, on_entry=self._on_warm,
-                    pace=warmup_pace).start()
+            with obs_trace.use(self._tracer):
+                if warmup == "sync":
+                    self.warmup = aot.precompile(entries,
+                                                 on_entry=self._on_warm)
+                else:
+                    self.warmup = aot.BackgroundWarmup(
+                        entries, on_entry=self._on_warm,
+                        pace=warmup_pace).start()
 
     # -- request path ------------------------------------------------------
     def select_action(self, features) -> Tuple[int, int, float, bool]:
@@ -279,16 +288,28 @@ class AutotuneServer:
 
     def step(self, force: bool = False) -> List[SolveResponse]:
         """Pump due micro-batches through solve -> reward -> Q-update."""
+        with obs_trace.use(self._tracer):
+            return self._step(force)
+
+    def _step(self, force: bool) -> List[SolveResponse]:
         done: List[SolveResponse] = []
         for entry in self.batcher.expire_overdue():
             done.append(self._complete_expired(entry))
+        if done and self._instr is not None:
+            self._instr.on_telemetry(self.telemetry, self.policy_version)
         for flush in self.batcher.pump(force=force):
-            self.telemetry.on_batch(flush.bucket, len(flush.req_ids),
-                                    flush.n_rows)
-            if self._instr is not None:
-                self._instr.on_flush(flush, self.pending)
-            for req_id, rec in zip(flush.req_ids, flush.records):
-                done.append(self._complete(req_id, rec, flush))
+            with obs_trace.span("flush.complete", tid=flush.flush_id,
+                                cat="flush", parent=flush.span_id,
+                                flush=flush.flush_id):
+                self.telemetry.on_batch(flush.bucket, len(flush.req_ids),
+                                        flush.n_rows)
+                if self._instr is not None:
+                    self._instr.on_flush(flush, self.pending)
+                for req_id, rec in zip(flush.req_ids, flush.records):
+                    done.append(self._complete(req_id, rec, flush))
+                if self._instr is not None:
+                    self._instr.on_telemetry(self.telemetry,
+                                             self.policy_version)
         return done
 
     def drain(self) -> List[SolveResponse]:

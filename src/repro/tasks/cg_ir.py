@@ -20,6 +20,7 @@ import numpy as np
 from repro.core.action_space import ActionSpace
 from repro.core.task import Outcome
 from repro.data.matrices import LinearSystem
+from repro.obs import trace as obs_trace
 from repro.solvers.cg import CGConfig, cg_ir_batch_lowerable
 from repro.tasks.base import LinearSystemTask, stack_fixed
 
@@ -40,8 +41,11 @@ class CGIRTask(LinearSystemTask):
 
     def solve_rows(self, rows, action_rows: Sequence[np.ndarray],
                    chunk: int) -> List[Outcome]:
-        A, b, x, acts, k = stack_fixed(rows, action_rows,
-                                       self.executor.preferred_chunk(chunk))
+        with obs_trace.span("flush.stack"):
+            A, b, x, acts, k = stack_fixed(
+                rows, action_rows, self.executor.preferred_chunk(chunk))
+        obs_trace.note("flush", input_bytes=int(
+            A.nbytes + b.nbytes + x.nbytes + acts.nbytes))
         cfg = self.solver_cfg_for(self.cg_cfg, A.shape[-1])
         # Value-keyed lowerable: dedupes the executable with any other
         # call site (or task) running the same (cfg, backend) program
@@ -50,8 +54,9 @@ class CGIRTask(LinearSystemTask):
             cg_ir_batch_lowerable(cfg, self.backend),
             (A, b, x, acts), A.shape[-1])
         # One host transfer for the whole stats tuple (DESIGN.md §7).
-        ferr, nbe, n_outer, n_cg, status, res = (
-            np.asarray(f) for f in jax.device_get(tuple(stats)))
+        with obs_trace.span("flush.fetch"):
+            ferr, nbe, n_outer, n_cg, status, res = (
+                np.asarray(f) for f in jax.device_get(tuple(stats)))
         return [Outcome(status=int(status[j]), cost=float(n_cg[j]),
                         metrics={"ferr": float(ferr[j]),
                                  "nbe": float(nbe[j]),

@@ -620,3 +620,197 @@ def test_snapshot_embeds_telemetry_evidence(warm_root, tmp_path):
     assert tel["latency_s_per_bucket"][bucket]["p99"] >= 0
     text = render_prometheus(srv.obs.registry)
     assert 'repro_service_snapshots_total{task="gmres_ir"} 1' in text
+
+
+# ---------------------------------------------------------------------------
+# Inline spans: parent links, the current tracer, the profiler hook
+# ---------------------------------------------------------------------------
+
+def test_inline_spans_link_parents_and_inherit_rows():
+    from repro.obs import trace
+    clock = FakeClock()
+    tr = Tracer(clock=clock)
+    with trace.span("orphan"):                 # no current tracer: no-op
+        pass
+    trace.note("outer", lost=1)                # nothing open: no-op
+    with trace.use(tr):
+        assert trace.current() is tr
+        with trace.span("outer", tid=5, cat="flush", a=1) as outer:
+            with trace.span("inner") as inner:
+                clock.advance(1.0)
+                trace.note("outer", late=2)
+    assert trace.current() is None and len(tr) == 2
+    by = {s.name: s for s in tr.spans()}
+    assert by["outer"].sid == outer and by["inner"].sid == inner
+    assert by["outer"].parent is None and by["inner"].parent == outer
+    assert (by["inner"].tid, by["inner"].cat) == (5, "flush")
+    assert by["outer"].args == {"a": 1, "late": 2}
+    assert tr.spans(tid=5) == []               # rows are per category
+    assert [s.name for s in tr.spans(tid=5, cat="flush")] == \
+        ["inner", "outer"]
+    ev = {e["name"]: e for e in tr.chrome_trace()["traceEvents"]}
+    assert ev["inner"]["args"] == {"sid": inner, "parent": outer}
+
+
+def _flush_run(root, obs, n, seed=21):
+    """`n` requests of one bucket through a server, drained: a forced
+    flush for n < 4, a full one at submit for n = 4."""
+    srv = _server(root, obs, seed=0)
+    reqs = _requests(n, seed=seed, n_range=(12, 14))
+    ids = [srv.submit(s) for s in reqs]
+    srv.drain()
+    return srv, reqs, [srv.poll(i) for i in ids]
+
+
+@pytest.mark.parametrize("n", [1, 3, 4])
+def test_flush_spans_form_one_tree_per_flush(warm_root, n):
+    from repro.tasks.base import stack_fixed
+    obs = Observability(registry=MetricsRegistry())
+    srv, reqs, resp = _flush_run(warm_root, obs, n)
+    spans = obs.tracer.spans(cat="flush")
+    assert sorted(s.name for s in spans) == [
+        "flush", "flush.complete", "flush.dispatch", "flush.fetch",
+        "flush.stack"]
+    by = {s.name: s for s in spans}
+    root = by["flush"]
+    fid = root.args["flush"]
+    assert root.parent is None and root.tid == fid
+    assert root.args["bucket"] == 16 and root.args["n_rows"] == 4
+    assert root.args["n_live"] == n
+    for name in ("flush.stack", "flush.dispatch", "flush.fetch"):
+        s = by[name]
+        assert s.parent == root.sid and s.tid == fid, name
+        assert root.t0 <= s.t0 <= s.t1 <= root.t1, name
+    assert by["flush.stack"].t1 <= by["flush.dispatch"].t0
+    assert by["flush.dispatch"].t1 <= by["flush.fetch"].t0
+    done = by["flush.complete"]
+    assert done.parent == root.sid and done.tid == fid
+    assert done.args == {"flush": fid} and done.t0 >= root.t1
+    for r in resp:
+        (solve,) = [s for s in obs.tracer.spans(tid=r.request_id)
+                    if s.name == "solve"]
+        assert solve.args["flush"] == fid
+    # input_bytes: the stacked A, b, x and action arrays, pad rows in.
+    A, b, x, acts, _ = stack_fixed(
+        [srv.task.prepare(s) for s in reqs],
+        [srv.action_space.actions[r.action] for r in resp], 4)
+    assert root.args["input_bytes"] == \
+        A.nbytes + b.nbytes + x.nbytes + acts.nbytes
+
+
+def test_annotate_hook_sees_the_ring_inline_spans_in_order(warm_root):
+    import contextlib
+    calls = []
+
+    def hook(name, **args):
+        calls.append((name, args))
+        return contextlib.nullcontext()
+
+    obs = Observability(registry=MetricsRegistry(),
+                        tracer=Tracer(annotate=hook))
+    srv, _, _ = _flush_run(warm_root, obs, 3)
+    assert srv.obs.tracer.annotate is hook     # the server kept it
+    inline = sorted((s for s in obs.tracer.spans() if s.sid),
+                    key=lambda s: s.sid)
+    assert [c[0] for c in calls] == [s.name for s in inline]
+    assert "flush" in [c[0] for c in calls]
+    (root,) = [s for s in inline if s.name == "flush"]
+    (args,) = [a for name, a in calls if name == "flush"]
+    assert args["flush"] == root.args["flush"] and args["bucket"] == 16
+    assert obs.registry.errors == 0
+
+
+class _Boom:
+    def __init__(self, where):
+        self.where = where
+
+    def __call__(self, name, **args):
+        if self.where == "call":
+            raise RuntimeError("hook down")
+        return self
+
+    def __enter__(self):
+        if self.where == "enter":
+            raise RuntimeError("hook down")
+
+    def __exit__(self, *exc):
+        if self.where == "exit":
+            raise RuntimeError("hook down")
+
+
+@pytest.mark.parametrize("where", ["call", "enter", "exit"])
+def test_raising_annotate_hook_never_changes_responses(warm_root, where):
+    _, _, base = _flush_run(warm_root, False, 3)
+    obs = Observability(registry=MetricsRegistry(),
+                        tracer=Tracer(annotate=_Boom(where)))
+    _, _, got = _flush_run(warm_root, obs, 3)
+    for r, b in zip(got, base):
+        assert r.request_id == b.request_id and r.action == b.action
+        assert r.reward == b.reward and r.state == b.state
+        assert int(r.record.status) == int(b.record.status)
+        assert float(r.record.cost) == float(b.record.cost)
+    assert obs.registry.errors >= 5            # one per flush-path span
+    text = render_prometheus(obs.registry)
+    assert f"repro_obs_errors_total {obs.registry.errors}" in text
+    names = {s.name for s in obs.tracer.spans(cat="flush")}
+    assert "flush" in names and "flush.complete" in names
+
+
+def test_reward_gauges_are_set_once_per_flush(warm_root):
+    reg = MetricsRegistry()
+    writes = []
+    reg.add_sink(lambda name, labels, value: writes.append(name))
+    srv, _, resp = _flush_run(warm_root, Observability(registry=reg), 3)
+    assert len(resp) == 3
+    assert writes.count("repro_service_reward_ewma") == 1
+    assert writes.count("repro_service_abs_rpe_ewma") == 1
+    assert writes.count("repro_service_policy_info") == 1
+    text = render_prometheus(reg)
+    assert lint_exposition(text) == []
+    tel = srv.telemetry
+    assert reg.gauge("repro_service_reward_ewma", "", ("task",)).labels(
+        task="gmres_ir").value == pytest.approx(tel.reward_ewma.value)
+    assert (f'repro_service_policy_info{{task="gmres_ir",'
+            f'version="{srv.policy_version}"}} 1') in text
+
+
+@pytest.mark.parametrize("mode, tau", [("sync", 4.25e-6),
+                                       ("background", 4.75e-6)])
+def test_warmup_records_lower_and_compile_per_bucket(mode, tau):
+    from repro.core import aot
+    from repro.core.features import PAPER_FEATURES
+    from repro.core import Discretizer, PrecisionPolicy, QTable
+    nf = len(PAPER_FEATURES)
+    disc = Discretizer.fit(
+        np.random.default_rng(0).normal(size=(8, nf)), [2] * nf)
+    policy = PrecisionPolicy(SPACE, disc,
+                             QTable(disc.n_states, SPACE.n_actions))
+    before = aot.cache_stats()
+    obs = Observability(registry=MetricsRegistry())
+    # A tau no other test uses: the grid's cells are cold here. The
+    # background sweep records from its own thread, into the tracer
+    # that was current where the server built it.
+    srv = AutotuneServer(
+        policy, IRConfig(tau=tau, i_max=4, m_max=12),
+        batcher_cfg=BatcherConfig(max_batch=2, max_wait_s=0.001,
+                                  bucket_step=16, min_bucket=16),
+        obs=obs, seed=0, warmup=mode, warmup_buckets=[12, 28])
+    if mode == "background":
+        srv.warmup.wait(timeout=600)
+    assert srv.warmup_state()["done"]
+    after = aot.cache_stats()
+    spans = obs.tracer.spans(cat="aot")
+    for bucket in (16, 32):
+        mine = [s for s in spans if s.args["bucket"] == bucket]
+        (parent,) = [s for s in mine if s.name == "aot.bucket"]
+        for name in ("aot.lower", "aot.compile"):
+            (s,) = [s for s in mine if s.name == name]
+            assert s.parent == parent.sid
+            assert s.args["rows"] == 2 and s.args["executor"] == "local"
+            assert parent.t0 <= s.t0 <= s.t1 <= parent.t1
+    for key in ("lower_s", "compile_s"):
+        name = "aot." + key[:-2]
+        spent = sum(s.duration for s in spans if s.name == name)
+        assert after[key] - before[key] == pytest.approx(spent, abs=1e-3)
+    assert srv.warmup_state()["elapsed_s"] >= \
+        round(after["lower_s"] - before["lower_s"], 3)
