@@ -45,20 +45,22 @@ def block_spec(block_shape, index_map, **kwargs) -> pl.BlockSpec:
     return pl.BlockSpec(block_shape, i32_map, **kwargs)
 
 
-def fmt_spec(n_params: int) -> pl.BlockSpec:
-    """SMEM block of the (1, n_params) format row, whatever the grid."""
-    return block_spec((1, n_params), lambda *_: (0, 0),
+def fmt_spec(n_params: int, rows: int = 1) -> pl.BlockSpec:
+    """SMEM block of the whole (rows, n_params) array of format rows,
+    whatever the grid."""
+    return block_spec((rows, n_params), lambda *_: (0, 0),
                       memory_space=pltpu.SMEM)
 
 
-def ref_chop(fmt_ref):
-    """Round-to-format closure over a kernel's SMEM format row
-    [t, emin, xmax_bits (int32 view), saturate, ...]. emax is implied by
-    xmax_bits, which is the only overflow check needed."""
-    t = fmt_ref[0, 0]
-    emin = fmt_ref[0, 1]
-    xmax_bits = fmt_ref[0, 2].astype(jnp.uint32)
-    saturate = fmt_ref[0, 3] != 0
+def ref_chop(fmt_ref, row=0):
+    """Round-to-format closure over row `row` (static or traced) of a
+    kernel's SMEM format rows [t, emin, xmax_bits (int32 view),
+    saturate, ...]. emax is implied by xmax_bits, which is the only
+    overflow check needed."""
+    t = fmt_ref[row, 0]
+    emin = fmt_ref[row, 1]
+    xmax_bits = fmt_ref[row, 2].astype(jnp.uint32)
+    saturate = fmt_ref[row, 3] != 0
 
     def chop_fn(x):
         return _chop_core(x, t, emin, 0, xmax_bits, saturate)

@@ -4,7 +4,9 @@ Covers the blocked-LU + blocked-trisolve subsystem:
   * property: blocked panel-pivoted factors solve the same systems as
     the strict factors (residual-level agreement across all format ids);
   * bit-exactness of the trisolve kernel vs its jnp oracle — padded and
-    unpadded, single and batched, lower and upper;
+    unpadded, single and batched (every system of a vmapped call in one
+    launch, one format per row), lower and upper — and the gauge of
+    systems per launch;
   * bit-exactness of the pinned-contract chopped GEMM
     (`backend.chop_matmul`) across backends, padded and batched;
   * the internal identity padding of `lu_factor_blocked` at sizes that
@@ -152,22 +154,61 @@ def test_trisolve_kernel_matches_oracle(fid, n, block, lower):
 
 
 @pytest.mark.parametrize("lower", [True, False], ids=["lower", "upper"])
-def test_trisolve_kernel_matches_oracle_batched(lower):
-    rng = np.random.default_rng(5)
-    Lus = jnp.asarray(np.stack([tri_factors(40, rng) for _ in range(3)]),
+@pytest.mark.parametrize("n,block", [(48, 16), (40, 16), (256, 128),
+                                     (200, 128)],
+                         ids=["unpadded", "padded", "unpadded-lane",
+                              "padded-lane"])
+@pytest.mark.parametrize("batch", [1, 3, 8, 11])
+def test_trisolve_kernel_matches_oracle_batched(batch, n, block, lower):
+    """The vmapped kernel runs every system of the batch in one launch,
+    one per sublane (grid groups of at most 8): each row must equal the
+    oracle and the same system solved alone, bit for bit, with a
+    different format per row (all seven ids across the cases)."""
+    rng = np.random.default_rng(1000 * batch + n)
+    Lus = jnp.asarray(np.stack([tri_factors(n, rng) for _ in range(batch)]),
                       jnp.float32)
-    bs = jnp.asarray(rng.standard_normal((3, 40)), jnp.float32)
-    got = jax.vmap(lambda L, b: trisolve_op(L, b, BF16, lower=lower,
-                                            block=16, interpret=True))(Lus, bs)
-    want = jax.vmap(lambda L, b: trisolve_ref(L, b, BF16, lower=lower,
-                                              block=16))(Lus, bs)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    bs = jnp.asarray(rng.standard_normal((batch, n)), jnp.float32)
+    fids = jnp.asarray((np.arange(batch) + batch + n) % len(FORMAT_LIST),
+                       jnp.int32)
+    got = jax.vmap(lambda L, b, f: trisolve_op(
+        L, b, f, lower=lower, block=block, interpret=True))(Lus, bs, fids)
+    want = jax.vmap(lambda L, b, f: trisolve_ref(
+        L, b, f, lower=lower, block=block))(Lus, bs, fids)
+    np.testing.assert_array_equal(np.asarray(got).view(np.uint32),
+                                  np.asarray(want).view(np.uint32))
     # ... and batched == single (row-independent solves).
-    for i in range(3):
-        single = trisolve_op(Lus[i], bs[i], BF16, lower=lower, block=16,
-                             interpret=True)
-        np.testing.assert_array_equal(np.asarray(single),
-                                      np.asarray(got)[i])
+    for i in range(batch):
+        single = trisolve_op(Lus[i], bs[i], fids[i], lower=lower,
+                             block=block, interpret=True)
+        np.testing.assert_array_equal(np.asarray(single).view(np.uint32),
+                                      np.asarray(got)[i].view(np.uint32))
+
+
+def test_trisolve_systems_per_launch_gauge():
+    """`repro_trisolve_systems_per_launch{bucket}` reads the systems one
+    traced launch solves: a flush's 8 rows for a vmapped bucket-256
+    solve (the blocked path's lower and upper launches), 1 unbatched."""
+    from repro.obs.metrics import default_registry
+    n = 256
+    LU = jnp.asarray(tri_factors(n), jnp.float32)
+    perm = jnp.arange(n)
+    b = jnp.ones((n,), jnp.float32)
+
+    def solve(L, p, v, fid):
+        return lu_solve(L, p, v, fid, backend=PALLAS)
+
+    def systems():
+        fam = default_registry().gauge("repro_trisolve_systems_per_launch",
+                                       "", ("bucket",))
+        return fam.labels(bucket=n).value
+
+    jax.make_jaxpr(jax.vmap(solve))(jnp.stack([LU] * 8),
+                                    jnp.stack([perm] * 8),
+                                    jnp.stack([b] * 8),
+                                    jnp.arange(8, dtype=jnp.int32) % 7)
+    assert systems() == 8
+    jax.make_jaxpr(solve)(LU, perm, b, jnp.int32(BF16))
+    assert systems() == 1
 
 
 def test_trisolve_matches_strict_solution_fp64():

@@ -11,6 +11,7 @@ The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker
 imports every test file.
 """
+import importlib.util
 import os
 
 import jax
@@ -25,6 +26,14 @@ from repro.kernels.trisolve import trisolve_op
 WIDTHS = (128, 256, 512)
 BATCH = 8
 PANEL = 64          # blocked-LU panel: the qgemm K the solver passes
+
+# The benchmark's roofline reader: it finds a kernel by its op name and
+# counts its work from the compiled custom call's operand shapes.
+_FLOPS_SPEC = importlib.util.spec_from_file_location(
+    "chip_flops", os.path.join(os.path.dirname(__file__), "..",
+                               "benchmarks", "chip", "flops.py"))
+flops = importlib.util.module_from_spec(_FLOPS_SPEC)
+_FLOPS_SPEC.loader.exec_module(flops)
 
 
 @pytest.fixture(scope="module")
@@ -109,3 +118,12 @@ def test_trisolve_compiles_for_v5e(one_chip, n, lower):
                           _shape(one_chip, (BATCH, n), jnp.float32),
                           _shape(one_chip, (BATCH,), jnp.int32))
     assert _kernel_calls(text, "trisolve") == 1
+    # One launch for the whole batch, its factor operand the vmapped
+    # call's column blocks: the shape `trisolve_roofline` counts.
+    line = next(ln for ln in text.splitlines()
+                if 'custom_call_target="tpu_custom_call"' in ln
+                and "/trisolve/pallas_call" in ln)
+    _, kernel, results, operands = flops.parse_custom_call(line)
+    assert kernel == "trisolve"
+    assert flops._largest(operands) == (BATCH, n // 128, n, 128)
+    assert flops.cost(kernel, results, operands)[0] == BATCH * n * n
