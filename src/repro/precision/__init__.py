@@ -1,6 +1,7 @@
 """Precision substrate: format descriptors, round-to-format emulation,
 and the backend dispatch layer (DESIGN.md §6)."""
-from .backend import (JnpBackend, PallasBackend, PrecisionBackend,
+from . import dw
+from .backend import (DoubleWord, JnpBackend, PallasBackend, PrecisionBackend,
                       available_backends, default_backend, register_backend,
                       resolve_backend, set_default_backend)
 from .chop import (chop, chop_matmul, chop_static, chop_stochastic,
@@ -18,7 +19,7 @@ __all__ = [
     "FORMATS", "FORMAT_LIST", "FORMAT_ID", "SOLVER_LADDER",
     "SOLVER_LADDER_FP8", "TPU_LADDER",
     "BF16", "FP16", "TF32", "FP32", "FP64", "E4M3", "E5M2", "runtime_tables",
-    "PrecisionBackend", "JnpBackend", "PallasBackend", "resolve_backend",
+    "PrecisionBackend", "JnpBackend", "DoubleWord", "dw", "PallasBackend", "resolve_backend",
     "default_backend", "set_default_backend", "register_backend",
     "available_backends",
 ]

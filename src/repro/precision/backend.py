@@ -47,6 +47,7 @@ import jax
 import jax.numpy as jnp
 
 from . import chop as _chop
+from . import dw as _dw
 
 ENV_VAR = "REPRO_PRECISION_BACKEND"
 
@@ -84,6 +85,12 @@ class PrecisionBackend:
         (strictly-lower + unit diagonal when `lower`, upper triangle
         including the diagonal otherwise) — DESIGN.md §6.2/§6.4."""
         raise NotImplementedError
+
+    @property
+    def carrier(self) -> str:
+        """The carrier's label in metrics: the dtype solves are coerced
+        to, or "native" (the caller's)."""
+        return self.carrier_dtype or "native"
 
     def coerce(self, *arrays: jnp.ndarray):
         """Cast float arrays to this backend's carrier dtype (no-op when
@@ -188,6 +195,73 @@ class PallasBackend(PrecisionBackend):
         from repro.kernels.trisolve import trisolve_op
         return trisolve_op(Lu, b, fmt_id, lower=lower, block=block,
                            interpret=self.interpret)
+
+
+@dataclasses.dataclass(frozen=True)
+class DoubleWord(PrecisionBackend):
+    """The double-word f32 carrier over a base backend (DESIGN.md §13).
+
+    Values that are `dw.DW` pairs carry 48 significand bits, so the
+    paper's fp64 steps run on a TPU; their ops are the `dw` module's
+    (plain jnp under XLA, exact products, pinned reductions), and
+    rounding to fp64 is the identity on the pair. Plain f32 arrays go to
+    `base` unchanged: its kernels serve every step in a format of at
+    most 24 bits. Frozen and value-hashed like the others, so it rides
+    as a static jit argument; the solvers pick their DW paths from its
+    type. The host hands it float64 rows, which `dw.stack_host` splits
+    into (hi, lo) before the transfer."""
+
+    base: PrecisionBackend = dataclasses.field(default_factory=JnpBackend)
+    name: str = dataclasses.field(default="double-word", init=False)
+    carrier_dtype: Optional[str] = dataclasses.field(default="float32",
+                                                     init=False)
+
+    def __post_init__(self):
+        if isinstance(self.base, DoubleWord):
+            raise TypeError("DoubleWord wraps an f32 backend, not another "
+                            "DoubleWord")
+
+    @property
+    def carrier(self) -> str:
+        return "double-word"
+
+    def chop(self, x, fmt_id):
+        if isinstance(x, _dw.DW):
+            return _dw.chop(x, fmt_id)
+        return self.base.chop(x, fmt_id)
+
+    def chop_mv(self, A, v, fmt_id, *, chop_output: bool = True):
+        if not (isinstance(A, _dw.DW) or isinstance(v, _dw.DW)):
+            return self.base.chop_mv(A, v, fmt_id, chop_output=chop_output)
+        out = _dw.matvec(_dw.chop(_as_dw(A), fmt_id),
+                         _dw.chop(_as_dw(v), fmt_id))
+        return _dw.chop(out, fmt_id) if chop_output else out
+
+    def chop_matmul(self, a, b, fmt_id, *, chop_inputs: bool = True,
+                    chop_output: bool = True):
+        if not (isinstance(a, _dw.DW) or isinstance(b, _dw.DW)):
+            return self.base.chop_matmul(a, b, fmt_id,
+                                         chop_inputs=chop_inputs,
+                                         chop_output=chop_output)
+        a, b = _as_dw(a), _as_dw(b)
+        if chop_inputs:
+            a, b = _dw.chop(a, fmt_id), _dw.chop(b, fmt_id)
+        bt = _dw.tmap(lambda m: m.T, b)
+        # One output row per step: a DW matvec of b^T with a row of a.
+        out = jax.lax.map(lambda row: _dw.matvec(bt, row), a)
+        return _dw.chop(out, fmt_id) if chop_output else out
+
+    def chop_trisolve(self, Lu, b, fmt_id, *, lower: bool,
+                      block: int = 128):
+        if not (isinstance(Lu, _dw.DW) or isinstance(b, _dw.DW)):
+            return self.base.chop_trisolve(Lu, b, fmt_id, lower=lower,
+                                           block=block)
+        b = _dw.chop(_as_dw(b), fmt_id)
+        return _dw.chop(_dw.trisolve(_as_dw(Lu), b, lower=lower), fmt_id)
+
+
+def _as_dw(x):
+    return x if isinstance(x, _dw.DW) else _dw.lift(x)
 
 
 # ---------------------------------------------------------------------------

@@ -81,6 +81,9 @@ class FlushResult:
     solve_s: float = 0.0
     flush_id: int = 0
     span_id: Optional[int] = None   # the `flush` span (None: not traced)
+    # The task's `flush_tags` for these rows (the span's extra args),
+    # e.g. which double-word branches the flush took.
+    tags: dict = dataclasses.field(default_factory=dict)
 
 
 class MicroBatcher:
@@ -126,9 +129,10 @@ class MicroBatcher:
                       ) -> FlushResult:
         target = self.flush_target(bucket)
         fid = next(_FLUSH_IDS)
+        tags = self._flush_tags(entries)
         with obs_trace.span("flush", tid=fid, cat="flush", flush=fid,
                             bucket=bucket, n_live=len(entries),
-                            n_rows=target) as sid:
+                            n_rows=target, **tags) as sid:
             t0, w0 = self.clock(), time.perf_counter()
             # Fault site: a raise here leaves the entries queued (pump()
             # only dequeues after a successful flush), so the flush is
@@ -150,7 +154,14 @@ class MicroBatcher:
             t1, w1 = self.clock(), time.perf_counter()
         return FlushResult(bucket, [e.req_id for e in entries], records,
                            target, t_solve_start=t0, t_solve_end=t1,
-                           solve_s=w1 - w0, flush_id=fid, span_id=sid)
+                           solve_s=w1 - w0, flush_id=fid, span_id=sid,
+                           tags=tags)
+
+    def _flush_tags(self, entries: List[_Pending]) -> dict:
+        """The task's args for this flush's span, from its real rows
+        (`flush_tags`, optional: a task without it adds none)."""
+        fn = getattr(self.task, "flush_tags", None)
+        return dict(fn([e.action_row for e in entries])) if fn else {}
 
     def expire_overdue(self, now: Optional[float] = None) -> List[_Pending]:
         """Remove and return every queued entry older than

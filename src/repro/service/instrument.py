@@ -32,11 +32,12 @@ class ServiceInstruments:
     """Per-server instrumentation facade (request path)."""
 
     def __init__(self, obs: Observability, task_name: str,
-                 executor_name: str):
+                 executor_name: str, carrier: str = "native"):
         self.obs = obs
         self.registry = obs.registry          # fail_open counts here
         self.task = str(task_name)
         self.executor = str(executor_name)
+        self.carrier = str(carrier)
         r = obs.registry
         self.requests = r.counter(
             "repro_service_requests_total",
@@ -56,6 +57,15 @@ class ServiceInstruments:
             "repro_service_solver_rows_total",
             "Rows solved, including fixed-shape padding.",
             ("task", "bucket"))
+        self.carrier_rows = r.counter(
+            "repro_solver_rows_total",
+            "Rows solved, including fixed-shape padding, by the carrier "
+            "their solves ran on.", ("task", "bucket", "carrier"))
+        self.dw_flushes = r.counter(
+            "repro_solver_dw_flushes_total",
+            "Flushes that took a double-word branch: branch=lu (some row "
+            "factors at fp64) or gmres (some row runs GMRES at fp64).",
+            ("task", "bucket", "branch"))
         self.pad_rows = r.counter(
             "repro_service_padded_rows_total",
             "Wasted padding rows from fixed-shape flushes.",
@@ -131,6 +141,11 @@ class ServiceInstruments:
         lab = dict(task=self.task, bucket=flush.bucket)
         self.batches.labels(executor=self.executor, **lab).inc()
         self.rows.labels(**lab).inc(flush.n_rows)
+        self.carrier_rows.labels(carrier=self.carrier, **lab).inc(
+            flush.n_rows)
+        for branch in ("lu", "gmres"):
+            if flush.tags.get(f"dw_{branch}"):
+                self.dw_flushes.labels(branch=branch, **lab).inc()
         self.pad_rows.labels(**lab).inc(flush.n_rows - n_live)
         self.pad_waste.labels(**lab).observe(
             (flush.n_rows - n_live) / max(flush.n_rows, 1))

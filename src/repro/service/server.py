@@ -200,7 +200,8 @@ class AutotuneServer:
         self.last_recovery: Optional[dict] = None
         self._instr = (ServiceInstruments(
             self.obs, getattr(self.task, "name", "unknown"),
-            self.executor.name) if self.obs is not None else None)
+            self.executor.name, getattr(self.task, "carrier", "native"))
+            if self.obs is not None else None)
         self._inflight: Dict[int, _InFlight] = {}
         # Bounded LRU retention for poll(): poll() evicts on retrieval,
         # and the oldest *unclaimed* responses are evicted past the cap

@@ -22,7 +22,7 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
-from repro.precision import fma_barrier, tree_sum
+from repro.precision import dw, fma_barrier, tree_sum
 
 
 def carrier_residual(A: jnp.ndarray, b: jnp.ndarray,
@@ -35,3 +35,13 @@ def carrier_norm(v: jnp.ndarray) -> jnp.ndarray:
     """||v||_2 with a pinned square-then-sum schedule (replaces
     `jnp.linalg.norm` on the unrounded inner-residual path)."""
     return jnp.sqrt(tree_sum(fma_barrier(v * v)))
+
+
+def carrier_residual_dw(A, b, x, sA=None):
+    """b - A x on the double-word carrier (DESIGN.md §13): exact
+    products, the pinned DW tree sum, one DW subtraction. `A`, `b`, `x`
+    are `dw.DW` pairs; `sA` is `dw.split(A.hi)` when the caller reuses
+    the matrix. Serves the u_r = fp64 residual of every outer iteration
+    and the Eq. 17 epilogue, whose nbe would otherwise have an f32
+    floor."""
+    return dw.sub(b, dw.matvec(A, x, sA))

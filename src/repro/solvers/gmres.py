@@ -25,7 +25,7 @@ from typing import NamedTuple
 import jax.numpy as jnp
 from jax import lax
 
-from repro.precision import resolve_backend, tree_sum
+from repro.precision import dw, resolve_backend, tree_sum
 
 from .blocking import resolve_blocking
 from .carrier import carrier_norm
@@ -164,4 +164,116 @@ def gmres_precond(A_g: jnp.ndarray, LU: jnp.ndarray, perm: jnp.ndarray,
     res_rel = jnp.abs(g[j]) / beta_safe
     fail = ~ok0 | ~jnp.all(jnp.isfinite(z))
     z = jnp.where(fail, jnp.zeros_like(z), z)
+    return GMRESResult(z, j, res_rel, fail)
+
+
+def gmres_precond_dw(A_g, LU, perm: jnp.ndarray, r, *, m_max: int,
+                     tol: float) -> GMRESResult:
+    """`gmres_precond` at u_g = fp64 on the double-word carrier
+    (DESIGN.md §13): `A_g`, `LU` and `r` are `dw.DW` pairs, and so is
+    every quantity the f32 path rounds to u_g. Rounding to fp64 is the
+    identity on the pair, so each operation is DW-exact and stored as it
+    is: the operator (DW matvec, the preconditioner's two DW
+    substitutions), MGS, the Givens recurrence and the back-substitution,
+    as the reference does them at the carrier's 48 bits. Convergence
+    tests read the high words. `z` is a DW pair; a zero `r` returns at
+    once (the rows of a batch that do not run this path)."""
+    n = r.hi.shape[-1]
+    f32 = jnp.float32
+    one = dw.lift(f32(1))
+    sA = dw.split(A_g.hi)
+
+    def precond(v):
+        v = dw.tmap(lambda a: a[perm], v)
+        return dw.trisolve(LU, dw.trisolve(LU, v, lower=True), lower=False)
+
+    def apply_op(v):
+        return precond(dw.matvec(A_g, v, sA))
+
+    rhat = precond(r)
+    beta = dw.norm2(rhat)
+    ok0 = jnp.isfinite(beta.hi) & (beta.hi > 0)
+    beta_safe = dw.where(ok0, beta, one)
+    v0 = dw.div(rhat, dw.tmap(lambda a: jnp.broadcast_to(a, (n,)),
+                              beta_safe))
+
+    V = dw.put(dw.zeros((m_max + 1, n)), 0,
+               dw.where(ok0, v0, dw.zeros((n,))))
+    R = dw.zeros((m_max + 1, m_max))
+    cs = dw.zeros((m_max,))
+    sn = dw.zeros((m_max,))
+    g = dw.put(dw.zeros((m_max + 1,)), 0, beta)
+
+    def cond(state):
+        *_, j, done = state
+        return (~done) & (j < m_max)
+
+    def body(state):
+        V, R, cs, sn, g, res_prev, j, done = state
+        w = apply_op(dw.get(V, j))
+
+        def mgs(i, carry):
+            w, h = carry
+            vi = dw.get(V, i)
+            hij = dw.dot(w, vi)
+            w = dw.sub(w, dw.scale(vi, hij))
+            return w, dw.put(h, i, hij)
+
+        w, h = lax.fori_loop(0, j + 1, mgs, (w, dw.zeros((m_max + 1,))))
+        hn = dw.norm2(w)
+        happy = hn.hi <= f32(1e-30)
+        hn_safe = dw.where(happy, one, hn)
+        vnext = dw.div(w, dw.tmap(lambda a: jnp.broadcast_to(a, (n,)),
+                                  hn_safe))
+        V = dw.put(V, j + 1, dw.where(happy, dw.zeros((n,)), vnext))
+        h = dw.put(h, j + 1, hn)
+
+        def rot(i, h):
+            hi, hi1 = dw.get(h, i), dw.get(h, i + 1)
+            c, s = dw.get(cs, i), dw.get(sn, i)
+            h = dw.put(h, i, dw.add(dw.mul(c, hi), dw.mul(s, hi1)))
+            return dw.put(h, i + 1, dw.sub(dw.mul(c, hi1), dw.mul(s, hi)))
+
+        h = lax.fori_loop(0, j, rot, h)
+        hj, hj1 = dw.get(h, j), dw.get(h, j + 1)
+        denom = dw.sqrt(dw.add(dw.mul(hj, hj), dw.mul(hj1, hj1)))
+        dsafe = dw.where(denom.hi == 0, one, denom)
+        c, s = dw.div(hj, dsafe), dw.div(hj1, dsafe)
+        cs = dw.put(cs, j, c)
+        sn = dw.put(sn, j, s)
+        h = dw.put(dw.put(h, j, denom), j + 1, dw.lift(f32(0)))
+        R = dw.put(R, j, h, axis=1)
+        gj = dw.get(g, j)
+        g = dw.put(dw.put(g, j, dw.mul(c, gj)), j + 1,
+                   dw.neg(dw.mul(s, gj)))
+
+        res = jnp.abs(dw.get(g, j + 1).hi)
+        fin = jnp.isfinite(res) & jnp.all(jnp.isfinite(h.hi))
+        stalled = (j >= 4) & (res > 0.95 * res_prev)
+        done = happy | (res <= tol * beta.hi) | stalled | ~fin
+        return V, R, cs, sn, g, res, j + 1, done
+
+    init = (V, R, cs, sn, g, jnp.asarray(jnp.inf, f32), jnp.int32(0), ~ok0)
+    V, R, cs, sn, g, _, j, done = lax.while_loop(cond, body, init)
+
+    # Back-substitute R y = g on the leading j x j block.
+    idx = jnp.arange(m_max)
+
+    def back(i, y):
+        row = m_max - 1 - i
+        rrow = dw.get(R, row)
+        prods = dw.where(idx > row, dw.mul(rrow, y), dw.zeros((m_max,)))
+        diag = dw.get(rrow, row)
+        dsafe = dw.where(diag.hi == 0, one, diag)
+        yi = dw.div(dw.sub(dw.get(g, row), dw.sum(prods)), dsafe)
+        return dw.put(y, row, dw.where(row < j, yi, dw.lift(f32(0))))
+
+    y = lax.fori_loop(0, m_max, back, dw.zeros((m_max,)))
+    Vm = dw.tmap(lambda a: a[:m_max], V)
+    z = dw.sum(dw.mul(Vm, dw.tmap(
+        lambda a: jnp.broadcast_to(a[:, None], (m_max, n)), y)), axis=0)
+
+    res_rel = jnp.abs(dw.get(g, j).hi) / beta_safe.hi
+    fail = ~ok0 | ~jnp.all(jnp.isfinite(z.hi))
+    z = dw.where(fail, dw.zeros((n,)), z)
     return GMRESResult(z, j, res_rel, fail)

@@ -32,12 +32,15 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.precision import resolve_backend, rounding_unit, tree_sum
+import numpy as np
+
+from repro.precision import (FORMAT_ID, DoubleWord, dw, resolve_backend,
+                             rounding_unit, tree_sum)
 
 from .blocking import DEFAULT_BLOCKING, BlockingPolicy
-from .carrier import carrier_residual
-from .gmres import chop_mv, gmres_precond
-from .lu import lu_factor_auto
+from .carrier import carrier_residual, carrier_residual_dw
+from .gmres import chop_mv, gmres_precond, gmres_precond_dw
+from .lu import LUFactors, lu_factor_auto, lu_factor_dw
 from .triangular import lu_solve
 
 
@@ -139,6 +142,153 @@ def _gmres_ir_impl(A, b, x_true, action, cfg, backend) -> SolveStats:
     return SolveStats(ferr, nbe, n_outer, n_gmres, status, res_norm)
 
 
+FP64_ID = FORMAT_ID["fp64"]
+
+
+def dw_branches(actions):
+    """(dw_lu, dw_gmres) of a flush on the double-word carrier: does some
+    row factor at u_f = fp64, does some row run GMRES at u_g = fp64.
+    `actions` is the flush's (rows, 4) format ids, numpy on the host (the
+    `flush` span's args) or traced on the device (the `lax.cond`
+    predicates): one rule for both. Pad rows repeat row 0, so they
+    change neither answer."""
+    return ((actions[..., 0] == FP64_ID).any(),
+            (actions[..., 2] == FP64_ID).any())
+
+
+def _gmres_ir_dw_row(A, b, x_true, action, LU, perm, lu_fail, dw_gmres,
+                     cfg, bk) -> SolveStats:
+    """One row of GMRES-IR on the double-word carrier (DESIGN.md §13).
+    A, b, x_true and LU are `dw.DW` pairs. x, the update and the
+    residual are DW, rounded to the row's runtime formats by `dw.chop`
+    (the identity at fp64). GMRES at u_g <= fp32 runs `base`'s f32 path
+    on the correctly rounded operands; at u_g = fp64 it runs
+    `gmres_precond_dw`, behind a `lax.cond` on `dw_gmres`, the flush's
+    unbatched predicate: under the batch's vmap the cond stays a cond,
+    so a flush whose rows all have u_g <= fp32 traces the DW GMRES but
+    never runs it. Each row takes the solver of its own u_g, so its
+    answer does not depend on the other rows of its flush."""
+    base = bk.base
+    f32 = jnp.float32
+    u, ug, ur = action[1], action[2], action[3]
+    g64 = ug == FP64_ID
+    A_g32 = dw.chop(A, ug).hi
+    A_r = dw.chop(A, ur)
+    sA_r = dw.split(A_r.hi)
+    b_r = dw.chop(b, ur)
+    zero = dw.zeros(b.hi.shape)
+    conv_tol = jnp.maximum(jnp.asarray(cfg.tau, f32), rounding_unit(u, f32))
+
+    def dw_solve(r):
+        gm = gmres_precond_dw(A, LU, perm, dw.where(g64, r, zero),
+                              m_max=cfg.m_max, tol=cfg.tol_inner)
+        return gm.z, gm.iters, gm.fail
+
+    def no_dw_solve(r):
+        return zero, jnp.int32(0), jnp.asarray(True)
+
+    def cond(state):
+        *_, done = state
+        return ~done
+
+    def body(state):
+        x, znorm_prev, i, n_gmres, status, done = state
+        r = dw.chop(dw.sub(b_r, dw.chop(dw.matvec(A_r, x, sA_r), ur)), ur)
+        # Rows at u_g = fp64 hand f32 GMRES a zero residual, the others
+        # hand it to the DW GMRES: each solver stops at once on those.
+        gm = gmres_precond(A_g32, LU.hi, perm, jnp.where(
+            g64, jnp.zeros_like(r.hi), dw.chop(r, ug).hi), ug,
+            m_max=cfg.m_max, tol=cfg.tol_inner, backend=base,
+            blocking=cfg.blocking)
+        zd, itd, faild = lax.cond(dw_gmres, dw_solve, no_dw_solve, r)
+        z = dw.where(g64, zd, dw.lift(gm.z))
+        iters = jnp.where(g64, itd, gm.iters)
+        gfail = jnp.where(g64, faild, gm.fail)
+        z = dw.chop(z, u)
+        x_new = dw.chop(dw.add(x, z), u)
+        znorm = _inf_norm(z.hi)
+        xnorm = _inf_norm(x_new.hi)
+        i_new = i + 1
+
+        converged = znorm <= conv_tol * xnorm
+        stagnated = (i > 0) & (znorm >= cfg.stag_tol * znorm_prev)
+        hit_max = i_new >= cfg.i_max
+        failed = gfail | ~jnp.all(jnp.isfinite(x_new.hi))
+
+        status = jnp.where(
+            failed, FAILED,
+            jnp.where(converged, CONVERGED,
+                      jnp.where(stagnated, STAGNATED,
+                                jnp.where(hit_max, MAXITER, status))))
+        done = converged | stagnated | hit_max | failed
+        x_new = dw.where(failed, x, x_new)
+        return (x_new, znorm, i_new, n_gmres + iters, status, done)
+
+    init_state = (zero, jnp.asarray(jnp.inf, f32), jnp.int32(0),
+                  jnp.int32(0), jnp.int32(MAXITER), lu_fail)
+    x, _, n_outer, n_gmres, status, _ = lax.while_loop(cond, body,
+                                                       init_state)
+    status = jnp.where(lu_fail, FAILED, status)
+
+    # Eq. 17 on the DW carrier: the residual and the error in DW, so
+    # nbe and ferr have no f32 floor; the norms read the high words.
+    res_norm = _inf_norm(carrier_residual_dw(A, b, x).hi)
+    normA = jnp.max(tree_sum(jnp.abs(A.hi), axis=1))
+    ferr = _inf_norm(dw.sub(x, x_true).hi) / _inf_norm(x_true.hi)
+    nbe = res_norm / (normA * _inf_norm(x.hi) + _inf_norm(b.hi))
+    inf = jnp.asarray(jnp.inf, f32)
+    ferr = jnp.where(jnp.isfinite(ferr), ferr, inf)
+    nbe = jnp.where(jnp.isfinite(nbe), nbe, inf)
+    return SolveStats(ferr, nbe, n_outer, n_gmres, status, res_norm)
+
+
+def _gmres_ir_dw_batch(A, b, x_true, actions, cfg, bk) -> SolveStats:
+    """The batched solve on the double-word carrier. A, b and x_true
+    arrive split by `dw.stack_host`: (rows, 2, ...) f32. The f32 LU of
+    every row runs as today, on the operands rounded correctly to u_f.
+    A flush with some row at u_f = fp64 (the all-fp64 arm) also runs
+    `lu_factor_dw`, one row at a time under its own cond, so only those
+    rows pay for it. Both flush predicates are computed here, outside
+    the vmap, so each `lax.cond` stays a cond."""
+    if cfg.init != "zero":
+        raise ValueError("the double-word carrier starts from x0 = 0 "
+                         f"(IRConfig.init='zero'), not {cfg.init!r}")
+    base = bk.base
+    A, b, x_true = dw.unstack(A), dw.unstack(b), dw.unstack(x_true)
+    dw_lu, dw_gmres = dw_branches(actions)
+    uf = actions[:, 0]
+    f64_rows = uf == FP64_ID
+    lu32 = jax.vmap(lambda Ai, fi: lu_factor_auto(
+        dw.chop(Ai, fi).hi, fi, backend=base, blocking=cfg.blocking))(
+            A, uf)
+
+    def with_dw_lu():
+        def one(args):
+            Ai, is64 = args
+            return lax.cond(is64, lu_factor_dw, lambda a: LUFactors(
+                dw.zeros(a.hi.shape), jnp.arange(a.hi.shape[-1]),
+                jnp.asarray(False)), Ai)
+
+        lud = lax.map(one, (A, f64_rows))
+        sel = f64_rows[:, None, None]
+        return (dw.DW(jnp.where(sel, lud.lu.hi, lu32.lu),
+                      jnp.where(sel, lud.lu.lo, 0)),
+                jnp.where(f64_rows[:, None], lud.perm, lu32.perm),
+                jnp.where(f64_rows, lud.fail, lu32.fail))
+
+    def without_dw_lu():
+        return dw.lift(lu32.lu), lu32.perm, lu32.fail
+
+    LU, perm, lu_fail = lax.cond(dw_lu, with_dw_lu, without_dw_lu)
+    return jax.vmap(lambda Ai, bi, xi, ai, Li, pi, fi: _gmres_ir_dw_row(
+        Ai, bi, xi, ai, Li, pi, fi, dw_gmres, cfg, bk))(
+            A, b, x_true, actions, LU, perm, lu_fail)
+
+
+def _is_dw(backend) -> bool:
+    return isinstance(backend, DoubleWord)
+
+
 # The backend is resolved *before* tracing and passed as a value-hashed
 # static argument: one executable per (shapes, cfg, backend), with the
 # action's format ids still runtime data (DESIGN.md §3.4, §6.3). The
@@ -150,6 +300,8 @@ _gmres_ir_jit = partial(jax.jit, static_argnames=("cfg", "backend"))(
 
 @partial(jax.jit, static_argnames=("cfg", "backend"))
 def _gmres_ir_batch_jit(A, b, x_true, actions, cfg, backend) -> SolveStats:
+    if _is_dw(backend):
+        return _gmres_ir_dw_batch(A, b, x_true, actions, cfg, backend)
     return jax.vmap(lambda Ai, bi, xi, ai:
                     _gmres_ir_impl(Ai, bi, xi, ai, cfg, backend)
                     )(A, b, x_true, actions)
@@ -166,6 +318,11 @@ def gmres_ir(A: jnp.ndarray, b: jnp.ndarray, x_true: jnp.ndarray,
     (DESIGN.md §6): an instance, a registry name, or None = default.
     """
     bk = resolve_backend(backend)
+    if _is_dw(bk):
+        out = gmres_ir_batch(*(np.asarray(a)[None]
+                               for a in (A, b, x_true, action)),
+                             cfg=cfg, backend=bk)
+        return SolveStats(*(f[0] for f in out))
     A, b, x_true = bk.coerce(jnp.asarray(A), jnp.asarray(b),
                              jnp.asarray(x_true))
     return _gmres_ir_jit(A, b, x_true, action, cfg, bk)
@@ -175,9 +332,24 @@ def gmres_ir_batch(A, b, x_true, actions, cfg: IRConfig = IRConfig(),
                    backend=None) -> SolveStats:
     """Batched (vmap) GMRES-IR: one episode sweep = one call."""
     bk = resolve_backend(backend)
+    if _is_dw(bk):
+        return _gmres_ir_batch_jit(*_dw_args(A, b, x_true, actions),
+                                   cfg, bk)
     A, b, x_true = bk.coerce(jnp.asarray(A), jnp.asarray(b),
                              jnp.asarray(x_true))
     return _gmres_ir_batch_jit(A, b, x_true, actions, cfg, bk)
+
+
+def _dw_args(A, b, x_true, actions):
+    """The batch's operands on the double-word carrier, outside the jit
+    boundary: float64 rows split into (hi, lo) f32 pairs by
+    `dw.stack_host`, in numpy when they are host arrays, so the transfer
+    carries no f64. Rows already split (one axis more than a batch of
+    matrices) pass as they are."""
+    if np.ndim(A) == 3:
+        A, b, x_true = (dw.stack_host(a) for a in (A, b, x_true))
+    return (jnp.asarray(A), jnp.asarray(b), jnp.asarray(x_true),
+            jnp.asarray(actions))
 
 
 def gmres_ir_batch_lowerable(cfg: IRConfig = IRConfig(), backend=None):
@@ -191,6 +363,8 @@ def gmres_ir_batch_lowerable(cfg: IRConfig = IRConfig(), backend=None):
     bk = resolve_backend(backend)
 
     def prepare(A, b, x_true, actions):
+        if _is_dw(bk):
+            return _dw_args(A, b, x_true, actions)
         A, b, x_true = bk.coerce(jnp.asarray(A), jnp.asarray(b),
                                  jnp.asarray(x_true))
         return A, b, x_true, jnp.asarray(actions)

@@ -38,7 +38,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.precision import resolve_backend
+from repro.precision import dw, resolve_backend
 
 from .blocking import resolve_blocking
 
@@ -175,6 +175,48 @@ def lu_factor_blocked(A: jnp.ndarray, fmt_id, block: int = 64,
     A1, perm, pivmin = carry
     A1, perm = A1[:n, :n], perm[:n]
     fail = (pivmin == 0) | ~jnp.all(jnp.isfinite(A1))
+    return LUFactors(A1, perm, fail)
+
+
+def lu_factor_dw(A) -> LUFactors:
+    """LU with partial pivoting on the double-word carrier: the paper's
+    u_f = fp64 step on a TPU (DESIGN.md §13). `A` is a `dw.DW` pair and
+    so are the factors. One `lax.fori_loop` step per column, each a
+    masked right-looking rank-1 update with exact products and DW
+    subtraction, so the traced program is one column's ops whatever n
+    is. The pivot is the largest |hi| of the column below the
+    diagonal."""
+    n = A.hi.shape[-1]
+    rows = jnp.arange(n)
+    zero = dw.zeros((n,))
+
+    def step(k, carry):
+        A, perm, pivmin = carry
+        col = dw.get(A, k, axis=1)
+        mag = jnp.where(rows >= k, jnp.abs(col.hi), -jnp.inf)
+        p = jnp.argmax(mag)
+        rk, rp = dw.get(A, k), dw.get(A, p)
+        A = dw.put(dw.put(A, k, rp), p, rk)
+        ek, ep = perm[k], perm[p]
+        perm = perm.at[k].set(ep).at[p].set(ek)
+        col = dw.get(A, k, axis=1)
+        pivot = dw.get(col, k)
+        pivmin = jnp.minimum(pivmin, jnp.abs(pivot.hi))
+        safe = dw.where(pivot.hi == 0, dw.lift(jnp.float32(1)), pivot)
+        factors = dw.where(rows > k, dw.div(col, dw.tmap(
+            lambda a: jnp.broadcast_to(a, (n,)), safe)), zero)
+        rowk = dw.get(A, k)
+        prod = dw.mul(dw.tmap(lambda a: a[:, None], factors),
+                      dw.tmap(lambda a: a[None, :], rowk))
+        upd = (rows[:, None] > k) & (rows[None, :] > k)
+        A = dw.where(upd, dw.sub(A, prod), A)
+        A = dw.put(A, k, dw.where(rows > k, factors, col), axis=1)
+        return A, perm, pivmin
+
+    A1, perm, pivmin = lax.fori_loop(
+        0, n, step, (A, rows, jnp.asarray(jnp.inf, jnp.float32)))
+    fail = ((pivmin == 0) | ~jnp.all(jnp.isfinite(A1.hi))
+            | ~jnp.all(jnp.isfinite(A1.lo)))
     return LUFactors(A1, perm, fail)
 
 

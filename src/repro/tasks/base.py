@@ -108,6 +108,11 @@ class LinearSystemTask:
     def feature_of(self, system: LinearSystem) -> np.ndarray:
         return feature_vector(system.features)
 
+    @property
+    def carrier(self) -> str:
+        """The label of the carrier this task's solves run on."""
+        return self.backend.carrier
+
     # -- shape bucketing ---------------------------------------------------
     def bucket_key(self, system: LinearSystem) -> int:
         return bucket_of(system.n, self.bucket_step, self.min_bucket)

@@ -22,7 +22,8 @@ from repro.core.action_space import ActionSpace
 from repro.core.batching import SolveRecord, solve_fixed_batch
 from repro.core.task import Outcome
 from repro.data.matrices import LinearSystem
-from repro.solvers.ir import IRConfig, gmres_ir_batch_lowerable
+from repro.precision import DoubleWord
+from repro.solvers.ir import IRConfig, dw_branches, gmres_ir_batch_lowerable
 from repro.tasks.base import LinearSystemTask
 
 
@@ -57,6 +58,16 @@ class GMRESIRTask(LinearSystemTask):
                                  cfg, chunk, backend=self.backend,
                                  executor=self.executor)
         return [outcome_of_record(r) for r in recs]
+
+    def flush_tags(self, action_rows: Sequence[np.ndarray]) -> dict:
+        """The `flush` span's args for these rows: on the double-word
+        carrier, which DW branches the flush takes (`dw_lu`,
+        `dw_gmres`), by the device predicate's own rule
+        (`solvers.ir.dw_branches`); nothing on the f32 carrier."""
+        if not isinstance(self.backend, DoubleWord):
+            return {}
+        dw_lu, dw_gmres = dw_branches(np.asarray(action_rows, np.int32))
+        return {"dw_lu": bool(dw_lu), "dw_gmres": bool(dw_gmres)}
 
     def lowerable_for(self, n_pad: int):
         """AOT form (DESIGN.md §12): the same (cfg, backend)-keyed
