@@ -280,58 +280,107 @@ def chop(x: DW, fmt_id) -> DW:
 
 # -- triangular substitution --------------------------------------------------
 
-@partial(jax.jit, static_argnames=("lower", "block"))
-def trisolve(LU: DW, b: DW, *, lower: bool, block: int = 8) -> DW:
-    """Substitution on the combined LU matrix in DW: the strict lower
-    triangle with a unit diagonal when `lower`, else the upper triangle
-    with its diagonal. One diagonal block of `block` unknowns per step
-    of a `lax.fori_loop` (the traced program is one step's ops whatever
-    n is): inside the block, unknown by unknown, y_i from the updated
-    right-hand side (divided by the diagonal when upper), then y_i times
-    column i of the block, exact products, subtracted in DW from the
-    block's unsolved entries; then the block's columns times its
-    unknowns, summed by the pinned tree, subtracted from every entry
-    still unsolved below (above) it. The block's own indices are static,
-    so a step is a few passes on a TPU, not one per unknown. n is padded
-    to a multiple of `block` with an identity tail, which solves to
-    zero and never couples back."""
+def _pad(LU: DW, b: DW, k: int):
+    """LU and b padded to a multiple of `k` with an identity tail,
+    which solves to zero and never couples back."""
     n = LU.hi.shape[-1]
-    k = min(block, n)
     npad = -(-n // k) * k
-    if npad != n:
-        eye = jnp.eye(npad, dtype=F32)
-        LU = DW(eye.at[:n, :n].set(LU.hi), jnp.zeros_like(eye).at[:n, :n]
-                .set(LU.lo))
-        b = tmap(lambda a: jnp.pad(a, (0, npad - n)), b)
+    if npad == n:
+        return LU, b
+    eye = jnp.eye(npad, dtype=F32)
+    return (DW(eye.at[:n, :n].set(LU.hi),
+               jnp.zeros_like(eye).at[:n, :n].set(LU.lo)),
+            tmap(lambda a: jnp.pad(a, (0, npad - n)), b))
+
+
+def _flip(x: DW) -> DW:
+    """Reverse every axis: an upper triangle becomes a lower one."""
+    return tmap(lambda a: jnp.flip(a), x)
+
+
+def _substitute(M: DW, b: DW, unit, k: int) -> DW:
+    """Forward substitution on the lower triangle of M, whose order is a
+    multiple of `k`; `unit` (a bool, possibly traced) takes the diagonal
+    as ones. One diagonal block of `k` unknowns per step of a
+    `lax.fori_loop` (the traced program is one step's ops whatever n
+    is): inside the block, unknown by unknown, y_i from the updated
+    right-hand side (divided by the diagonal unless `unit`), then y_i
+    times column i of the block, exact products, subtracted in DW from
+    the block's unsolved entries; then the block's columns times its
+    unknowns, summed by the pinned tree, subtracted from every entry
+    below it. The block's own indices are static, so a step is a few
+    passes on a TPU, not one per unknown."""
+    npad = M.hi.shape[-1]
     idx = jnp.arange(npad)
-    order = range(k) if lower else range(k - 1, -1, -1)
 
     def step(s, carry):
         r, y = carry
-        i0 = s * k if lower else npad - (s + 1) * k
-        D = tmap(lambda a: lax.dynamic_slice(a, (i0, i0), (k, k)), LU)
+        i0 = s * k
+        D = tmap(lambda a: lax.dynamic_slice(a, (i0, i0), (k, k)), M)
         rb = tmap(lambda a: lax.dynamic_slice(a, (i0,), (k,)), r)
-        ys = [None] * k
-        for t in order:
+        ys = []
+        for t in range(k):
             yt = tmap(lambda a: a[t], rb)
-            if not lower:
-                d = tmap(lambda a: a[t, t], D)
-                yt = div(yt, where(d.hi == _ZERO, lift(_ONE), d))
-            ys[t] = yt
-            rest = (np.arange(k) > t) if lower else (np.arange(k) < t)
-            if rest.any():
-                rb = where(rest, sub(rb, mul(tmap(lambda a: a[:, t], D),
-                                             tmap(lambda a: jnp.broadcast_to(
-                                                 a, (k,)), yt))), rb)
+            d = tmap(lambda a: a[t, t], D)
+            yt = where(unit, yt, div(yt, where(d.hi == _ZERO, lift(_ONE), d)))
+            ys.append(yt)
+            if t < k - 1:
+                rb = where(np.arange(k) > t,
+                           sub(rb, mul(tmap(lambda a: a[:, t], D),
+                                       tmap(lambda a: jnp.broadcast_to(
+                                           a, (k,)), yt))), rb)
         yb = DW(jnp.stack([v.hi for v in ys]), jnp.stack([v.lo for v in ys]))
-        cols = tmap(lambda a: lax.dynamic_slice(a, (0, i0), (npad, k)), LU)
+        cols = tmap(lambda a: lax.dynamic_slice(a, (0, i0), (npad, k)), M)
         off = sum(mul(cols, tmap(lambda a: jnp.broadcast_to(
             a, (npad, k)), yb)), -1)
-        left = (idx >= i0 + k) if lower else (idx < i0)
-        r = where(left, sub(r, off), r)
+        r = where(idx >= i0 + k, sub(r, off), r)
         y = DW(lax.dynamic_update_slice(y.hi, yb.hi, (i0,)),
                lax.dynamic_update_slice(y.lo, yb.lo, (i0,)))
         return r, y
 
-    y = lax.fori_loop(0, npad // k, step, (b, zeros((npad,))))[1]
+    return lax.fori_loop(0, npad // k, step, (b, zeros((npad,))))[1]
+
+
+@partial(jax.jit, static_argnames=("lower", "block"))
+def trisolve(LU: DW, b: DW, *, lower: bool, block: int = 8) -> DW:
+    """Substitution on the combined LU matrix in DW: the strict lower
+    triangle with a unit diagonal when `lower`, else the upper triangle
+    with its diagonal, solved as the lower triangle of the reversed
+    matrix (`_substitute`). With a block of a power of two, the pinned
+    tree sums the reversed block in the same pairs, so both directions
+    round alike."""
+    n = LU.hi.shape[-1]
+    k = min(block, n)
+    LU, b = _pad(LU, b, k)
+    if lower:
+        y = _substitute(LU, b, True, k)
+    else:
+        y = _flip(_substitute(_flip(LU), _flip(b), False, k))
     return tmap(lambda a: a[:n], y)
+
+
+@partial(jax.jit, static_argnames="block")
+def lu_factors(LU: DW, block: int = 8) -> DW:
+    """The combined LU matrix as `lu_solve` takes it: padded to a
+    multiple of `block`, stacked with its reverse, (2, npad, npad)."""
+    k = min(block, LU.hi.shape[-1])
+    LU, _ = _pad(LU, zeros(LU.hi.shape[-1:]), k)
+    return DW(jnp.stack([LU.hi, jnp.flip(LU.hi)]),
+              jnp.stack([LU.lo, jnp.flip(LU.lo)]))
+
+
+@partial(jax.jit, static_argnames="block")
+def lu_solve(F: DW, b: DW, block: int = 8) -> DW:
+    """U^-1 L^-1 b for the factors `F = lu_factors(LU, block)`: both
+    substitutions run through one traced `_substitute` step, in a loop
+    of two passes, the second on the reversed factors; the same
+    arithmetic as `trisolve` lower, then upper, in half the program."""
+    n = b.hi.shape[-1]
+    npad = F.hi.shape[-1]
+    k = min(block, n)
+    b = tmap(lambda a: jnp.pad(a, (0, npad - n)), b)
+
+    def solve(p, v):
+        return _flip(_substitute(get(F, p), v, p == 0, k))
+
+    return tmap(lambda a: a[:n], lax.fori_loop(0, 2, solve, b))

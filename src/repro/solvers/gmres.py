@@ -182,10 +182,10 @@ def gmres_precond_dw(A_g, LU, perm: jnp.ndarray, r, *, m_max: int,
     f32 = jnp.float32
     one = dw.lift(f32(1))
     sA = dw.split(A_g.hi)
+    F = dw.lu_factors(LU)
 
     def precond(v):
-        v = dw.tmap(lambda a: a[perm], v)
-        return dw.trisolve(LU, dw.trisolve(LU, v, lower=True), lower=False)
+        return dw.lu_solve(F, dw.tmap(lambda a: a[perm], v))
 
     def apply_op(v):
         return precond(dw.matvec(A_g, v, sA))

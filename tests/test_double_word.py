@@ -124,6 +124,20 @@ def test_dw_trisolve_matches_float64(lower, n):
     assert np.max(np.abs(got - want)) <= 2.0 ** -44 * np.max(np.abs(want))
 
 
+@pytest.mark.parametrize("n", [5, 36, 64])
+def test_dw_lu_solve_is_both_substitutions_bit_for_bit(n):
+    # One traced substitution in two passes, the second on the reversed
+    # factors, rounds as the lower then the upper `trisolve` do.
+    rng = np.random.default_rng(n)
+    M = rng.standard_normal((n, n)) / n + np.diag(1.0 + rng.random(n))
+    LU, v = _pair(M), _pair(rng.standard_normal(n))
+    want = dw.trisolve(LU, dw.trisolve(LU, v, lower=True), lower=False)
+    got = dw.lu_solve(dw.lu_factors(LU), v)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g).view(np.uint32),
+                                      np.asarray(w).view(np.uint32))
+
+
 def _chop_inputs(t, kind):
     """DW pairs and their float64 values: random pairs, or pairs whose
     high word lies exactly halfway between two t-bit numbers, with a
