@@ -183,34 +183,37 @@ def gmres_precond_dw(A_g, LU, perm: jnp.ndarray, r, *, m_max: int,
     one = dw.lift(f32(1))
     sA = dw.split(A_g.hi)
     F = dw.lu_factors(LU)
+    zn = dw.zeros((n,))
 
-    def precond(v):
-        return dw.lu_solve(F, dw.tmap(lambda a: a[perm], v))
-
-    def apply_op(v):
-        return precond(dw.matvec(A_g, v, sA))
-
-    rhat = precond(r)
-    beta = dw.norm2(rhat)
-    ok0 = jnp.isfinite(beta.hi) & (beta.hi > 0)
-    beta_safe = dw.where(ok0, beta, one)
-    v0 = dw.div(rhat, dw.tmap(lambda a: jnp.broadcast_to(a, (n,)),
-                              beta_safe))
-
-    V = dw.put(dw.zeros((m_max + 1, n)), 0,
-               dw.where(ok0, v0, dw.zeros((n,))))
-    R = dw.zeros((m_max + 1, m_max))
-    cs = dw.zeros((m_max,))
-    sn = dw.zeros((m_max,))
-    g = dw.put(dw.zeros((m_max + 1,)), 0, beta)
+    def safe(x, ok):
+        return dw.tmap(lambda a: jnp.broadcast_to(a, (n,)),
+                       dw.where(ok, x, one))
 
     def cond(state):
         *_, j, done = state
         return (~done) & (j < m_max)
 
+    def start(w, state):
+        # Step j = -1: w = M^-1 r gives beta, v_0 and g_0.
+        V, R, cs, sn, g, _, res_prev, _, _ = state
+        beta = dw.norm2(w)
+        ok0 = jnp.isfinite(beta.hi) & (beta.hi > 0)
+        V = dw.put(V, 0, dw.where(ok0, dw.div(w, safe(beta, ok0)), zn))
+        return (V, R, cs, sn, dw.put(g, 0, beta), beta, res_prev,
+                jnp.int32(0), ~ok0)
+
     def body(state):
-        V, R, cs, sn, g, res_prev, j, done = state
-        w = apply_op(dw.get(V, j))
+        j = state[-2]
+        # One call site of the operator and the preconditioner, so the
+        # program holds one copy of the substitution: step -1 applies
+        # M^-1 to r, every later step to A v_j.
+        u = dw.where(j < 0, r, dw.matvec(
+            A_g, dw.get(state[0], jnp.maximum(j, 0)), sA))
+        w = dw.lu_solve(F, dw.tmap(lambda a: a[perm], u))
+        return lax.cond(j < 0, start, arnoldi, w, state)
+
+    def arnoldi(w, state):
+        V, R, cs, sn, g, beta, res_prev, j, done = state
 
         def mgs(i, carry):
             w, h = carry
@@ -222,10 +225,8 @@ def gmres_precond_dw(A_g, LU, perm: jnp.ndarray, r, *, m_max: int,
         w, h = lax.fori_loop(0, j + 1, mgs, (w, dw.zeros((m_max + 1,))))
         hn = dw.norm2(w)
         happy = hn.hi <= f32(1e-30)
-        hn_safe = dw.where(happy, one, hn)
-        vnext = dw.div(w, dw.tmap(lambda a: jnp.broadcast_to(a, (n,)),
-                                  hn_safe))
-        V = dw.put(V, j + 1, dw.where(happy, dw.zeros((n,)), vnext))
+        vnext = dw.div(w, safe(hn, ~happy))
+        V = dw.put(V, j + 1, dw.where(happy, zn, vnext))
         h = dw.put(h, j + 1, hn)
 
         def rot(i, h):
@@ -251,10 +252,15 @@ def gmres_precond_dw(A_g, LU, perm: jnp.ndarray, r, *, m_max: int,
         fin = jnp.isfinite(res) & jnp.all(jnp.isfinite(h.hi))
         stalled = (j >= 4) & (res > 0.95 * res_prev)
         done = happy | (res <= tol * beta.hi) | stalled | ~fin
-        return V, R, cs, sn, g, res, j + 1, done
+        return V, R, cs, sn, g, beta, res, j + 1, done
 
-    init = (V, R, cs, sn, g, jnp.asarray(jnp.inf, f32), jnp.int32(0), ~ok0)
-    V, R, cs, sn, g, _, j, done = lax.while_loop(cond, body, init)
+    init = (dw.zeros((m_max + 1, n)), dw.zeros((m_max + 1, m_max)),
+            dw.zeros((m_max,)), dw.zeros((m_max,)), dw.zeros((m_max + 1,)),
+            dw.zeros(()), jnp.asarray(jnp.inf, f32), jnp.int32(-1),
+            jnp.asarray(False))
+    V, R, cs, sn, g, beta, _, j, done = lax.while_loop(cond, body, init)
+    ok0 = jnp.isfinite(beta.hi) & (beta.hi > 0)
+    beta_safe = dw.where(ok0, beta, one)
 
     # Back-substitute R y = g on the leading j x j block.
     idx = jnp.arange(m_max)
