@@ -83,26 +83,26 @@ def solve_fixed_batch(A_rows: Sequence[np.ndarray],
     padded to exactly the executor's `preferred_chunk(chunk)` rows by
     repeating row 0, keeping the compiled shape constant. Returns one
     SolveRecord per *input* row (pad rows dropped). `backend` selects
-    the precision backend (DESIGN.md §6); the solver entry point coerces
-    rows to the backend's carrier dtype. `executor` selects device
-    placement (DESIGN.md §7): None/"local" is the historical
-    single-device vmapped path, "sharded" lays the batch over a device
-    mesh. Buckets at or above `ir_cfg.blocking.min_n` run the blocked
-    LU + trisolve hot path (DESIGN.md §6.4) inside the same vmapped
-    executable.
+    the precision backend (DESIGN.md §6); the rows are stacked in its
+    `host_dtype` (`tasks.base.stack_flush`), so the Pallas backend's
+    f32 cast happens on the host, the jnp backend's rows keep their
+    dtype, and `DoubleWord` gets float64 rows to split. The `flush`
+    span's `input_bytes` and `input_dtype` say what crosses to the
+    device. `executor` selects device placement (DESIGN.md §7):
+    None/"local" is the historical single-device vmapped path,
+    "sharded" lays the batch over a device mesh. Buckets at or above
+    `ir_cfg.blocking.min_n` run the blocked LU + trisolve hot path
+    (DESIGN.md §6.4) inside the same vmapped executable.
     """
     from repro.precision import resolve_backend
-    from repro.tasks.base import stack_fixed
+    from repro.tasks.base import stack_flush
     ex = resolve_executor(executor)
     bk = resolve_backend(backend)
-    # Flush-path spans (DESIGN.md §8.4): no-ops unless the server made
-    # its tracer current around this flush.
-    with obs_trace.span("flush.stack"):
-        A, b, x, acts, k = stack_fixed(
-            list(zip(A_rows, b_rows, x_rows)), action_rows,
-            ex.preferred_chunk(chunk))
-    obs_trace.note("flush", input_bytes=int(
-        A.nbytes + b.nbytes + x.nbytes + acts.nbytes))
+    # Flush-path spans and notes (DESIGN.md §8.4): no-ops unless the
+    # server made its tracer current around this flush.
+    A, b, x, acts, k = stack_flush(list(zip(A_rows, b_rows, x_rows)),
+                                   action_rows, ex.preferred_chunk(chunk),
+                                   bk)
     # The solver rides as a `LowerableCall`, which both keys the
     # dispatcher memo by computation value — every call site with equal
     # (cfg, backend) shares one executable per shape, across tasks —

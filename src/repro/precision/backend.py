@@ -62,10 +62,19 @@ class PrecisionBackend:
     class only documents the contract and hosts shared helpers).
 
     `carrier_dtype` is the float dtype the backend's solver entry points
-    coerce operands to (None = keep the caller's carrier)."""
+    coerce operands to (None = keep the caller's carrier). `host_dtype`
+    is the float dtype those entry points take from the host: a flush's
+    rows are stacked in it (`tasks.base.stack_fixed`), so a carrier cast
+    it implies happens in numpy, before the transfer."""
 
     name: str = "abstract"
     carrier_dtype: Optional[str] = None
+
+    @property
+    def host_dtype(self) -> Optional[str]:
+        """The dtype a flush's float rows are stacked in on the host
+        (None = as given)."""
+        return None
 
     def chop(self, x: jnp.ndarray, fmt_id) -> jnp.ndarray:
         raise NotImplementedError
@@ -147,12 +156,17 @@ class PallasBackend(PrecisionBackend):
     The kernels are compiled for the TPU; `interpret=True` runs them
     through the Pallas interpreter instead (CPU tests). `chop_min_elems`
     routes small glue arrays to the bit-identical jnp chop to avoid
-    kernel launch overhead."""
+    kernel launch overhead. Its host dtype is the carrier's: rows leave
+    the host as f32, and `coerce` finds nothing to cast."""
 
     name: str = dataclasses.field(default="pallas", init=False)
     carrier_dtype: Optional[str] = "float32"
     interpret: bool = False
     chop_min_elems: int = DEFAULT_CHOP_MIN_ELEMS
+
+    @property
+    def host_dtype(self) -> Optional[str]:
+        return self.carrier_dtype
 
     def chop(self, x, fmt_id):
         x = jnp.asarray(x)
@@ -208,8 +222,9 @@ class DoubleWord(PrecisionBackend):
     `base` unchanged: its kernels serve every step in a format of at
     most 24 bits. Frozen and value-hashed like the others, so it rides
     as a static jit argument; the solvers pick their DW paths from its
-    type. The host hands it float64 rows, which `dw.stack_host` splits
-    into (hi, lo) before the transfer."""
+    type. The host hands it float64 rows (`host_dtype`), which
+    `dw.stack_host` splits into (hi, lo) before the transfer: a cast to
+    the f32 `carrier_dtype` there would drop every low word."""
 
     base: PrecisionBackend = dataclasses.field(default_factory=JnpBackend)
     name: str = dataclasses.field(default="double-word", init=False)
@@ -224,6 +239,10 @@ class DoubleWord(PrecisionBackend):
     @property
     def carrier(self) -> str:
         return "double-word"
+
+    @property
+    def host_dtype(self) -> Optional[str]:
+        return "float64"
 
     def chop(self, x, fmt_id):
         if isinstance(x, _dw.DW):

@@ -358,7 +358,13 @@ def gmres_ir_batch_lowerable(cfg: IRConfig = IRConfig(), backend=None):
     jitted entry point, but AOT-compilable — `lower().compile()` per
     shape — and value-keyed by (entry point, cfg, backend), so every
     task and call site running this program shares one executable per
-    shape (DESIGN.md §12)."""
+    shape (DESIGN.md §12).
+
+    `prepare` takes rows in `bk.host_dtype`, the dtype the flush path
+    stacks them in (`tasks.base.stack_fixed`): f32 on the Pallas
+    backend, so `coerce` casts nothing and no f64 buffer reaches the
+    device; float64 on `DoubleWord`, split here into (hi, lo) f32
+    pairs; as given on the jnp backend."""
     from repro.core.executor import LowerableCall
     bk = resolve_backend(backend)
 

@@ -22,24 +22,48 @@ from repro.core.features import PAPER_FEATURES, feature_vector
 from repro.core.rewards import reward as reward_fn
 from repro.core.task import Outcome, bucket_of
 from repro.data.matrices import LinearSystem, pad_system
+from repro.obs import trace as obs_trace
 from repro.precision import resolve_backend
 
 
 def stack_fixed(rows: Sequence[Tuple[np.ndarray, np.ndarray, np.ndarray]],
-                action_rows: Sequence[np.ndarray], chunk: int):
+                action_rows: Sequence[np.ndarray], chunk: int,
+                dtype: Optional[str] = None):
     """Stack padded (A, b, x) rows + action rows into fixed-shape arrays.
 
     The batch dimension is padded to exactly `chunk` by repeating row 0,
     keeping the compiled shape constant; callers drop the pad rows from
     the results (`k` = number of real rows).
+
+    `dtype` is the float dtype A, b and x are stacked in, in the same
+    numpy pass (None = the rows' own). Callers pass their backend's
+    `host_dtype`: float32 on the Pallas backend, so its carrier cast
+    (round to nearest even, as XLA's convert) happens here and no f64
+    array crosses to the device; float64 on `DoubleWord`, whose
+    `prepare` splits the rows into (hi, lo); None on the jnp backend.
     """
     k = len(rows)
     assert 0 < k <= chunk, (k, chunk)
     idx = list(range(k)) + [0] * (chunk - k)
-    A = np.stack([rows[i][0] for i in idx])
-    b = np.stack([rows[i][1] for i in idx])
-    x = np.stack([rows[i][2] for i in idx])
+    A = np.stack([rows[i][0] for i in idx], dtype=dtype)
+    b = np.stack([rows[i][1] for i in idx], dtype=dtype)
+    x = np.stack([rows[i][2] for i in idx], dtype=dtype)
     acts = np.stack([np.asarray(action_rows[i], np.int32) for i in idx])
+    return A, b, x, acts, k
+
+
+def stack_flush(rows, action_rows: Sequence[np.ndarray], chunk: int,
+                backend):
+    """A live flush's `stack_fixed`, in `backend.host_dtype`, timed as
+    the `flush.stack` span. The `flush` span then notes what crosses to
+    the device: `input_bytes` (A, b, x and actions, pad rows in) and
+    `input_dtype` (the stacked A's dtype)."""
+    with obs_trace.span("flush.stack"):
+        A, b, x, acts, k = stack_fixed(rows, action_rows, chunk,
+                                       dtype=backend.host_dtype)
+    obs_trace.note("flush", input_bytes=int(
+        A.nbytes + b.nbytes + x.nbytes + acts.nbytes),
+        input_dtype=str(A.dtype))
     return A, b, x, acts, k
 
 
@@ -160,10 +184,10 @@ class LinearSystemTask:
     def precompile_bucket(self, bucket: int, chunk: int) -> bool:
         """AOT-build this task's executable for (bucket, chunk) without
         solving anything (DESIGN.md §12). The warm batch is shaped
-        exactly like a live flush — `stack_fixed` to the executor's
-        preferred chunk, int32 action rows — so the first real request
-        hits the compiled executable. Returns False when the task has
-        no AOT form."""
+        exactly like a live flush — `stack_fixed` in the backend's host
+        dtype to the executor's preferred chunk, int32 action rows — so
+        the first real request hits the compiled executable. Returns
+        False when the task has no AOT form."""
         low = self.lowerable_for(int(bucket))
         if low is None or self.action_space is None:
             return False
@@ -171,7 +195,8 @@ class LinearSystemTask:
         action = np.asarray(self.action_space.actions[0], np.int32)
         A, b, x, acts, _ = stack_fixed(
             [row], [action],
-            self.executor.preferred_chunk(int(chunk), int(bucket)))
+            self.executor.preferred_chunk(int(chunk), int(bucket)),
+            dtype=self.backend.host_dtype)
         return bool(self.executor.precompile(low, (A, b, x, acts),
                                              A.shape[-1]))
 

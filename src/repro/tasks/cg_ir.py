@@ -22,7 +22,7 @@ from repro.core.task import Outcome
 from repro.data.matrices import LinearSystem
 from repro.obs import trace as obs_trace
 from repro.solvers.cg import CGConfig, cg_ir_batch_lowerable
-from repro.tasks.base import LinearSystemTask, stack_fixed
+from repro.tasks.base import LinearSystemTask, stack_flush
 
 
 class CGIRTask(LinearSystemTask):
@@ -41,11 +41,9 @@ class CGIRTask(LinearSystemTask):
 
     def solve_rows(self, rows, action_rows: Sequence[np.ndarray],
                    chunk: int) -> List[Outcome]:
-        with obs_trace.span("flush.stack"):
-            A, b, x, acts, k = stack_fixed(
-                rows, action_rows, self.executor.preferred_chunk(chunk))
-        obs_trace.note("flush", input_bytes=int(
-            A.nbytes + b.nbytes + x.nbytes + acts.nbytes))
+        A, b, x, acts, k = stack_flush(
+            rows, action_rows, self.executor.preferred_chunk(chunk),
+            self.backend)
         cfg = self.solver_cfg_for(self.cg_cfg, A.shape[-1])
         # Value-keyed lowerable: dedupes the executable with any other
         # call site (or task) running the same (cfg, backend) program
