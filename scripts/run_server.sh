@@ -15,12 +15,12 @@
 #                            disk with zero fresh compiles. Unset: the
 #                            server keeps it at .cache/xla under the repo
 #                            root.
-#   REPRO_SOLVE_EXECUTOR     solve executor registry name (§7):
+#   REPRO_SOLVE_EXECUTOR     solve executor name (§7):
 #                            local | sharded (a mesh over every device
 #                            the host exposes). Default: local.
-#   REPRO_PRECISION_BACKEND  precision backend registry name (§6):
-#                            jnp | pallas | ... Default: the platform's
-#                            (compiled pallas on a TPU, jnp elsewhere).
+#                            The precision backend is the platform's
+#                            (§6.1): compiled pallas on a TPU, jnp
+#                            elsewhere.
 #   JAX_ENABLE_X64           the solvers' fp64 carrier on CPU hosts (§2).
 #                            Pinned on — the bit-parity contract assumes
 #                            it. A TPU serves the f32 carrier.

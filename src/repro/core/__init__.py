@@ -29,11 +29,9 @@ from .discretize import Discretizer
 from .engine import AutotuneEngine
 from .env import GMRESIREnv
 from .executor import (LocalExecutor, LowerableCall, ShardedExecutor,
-                       SolveExecutor, available_executors,
-                       computation_key, default_executor,
+                       SolveExecutor, computation_key,
                        executor_compile_count, executor_compile_log,
-                       register_executor, resolve_executor,
-                       set_default_executor)
+                       resolve_executor)
 from .policy import PrecisionPolicy
 from .rewards import (RewardConfig, W1, W2, accuracy_term, penalty_term,
                       precision_term, reward, reward_batch)
@@ -44,8 +42,7 @@ __all__ = [
     "ActionSpace", "fp8_reduced_action_space", "full_action_space",
     "is_monotone", "reduced_action_space", "reduced_size",
     "SolveExecutor", "LocalExecutor", "LowerableCall", "ShardedExecutor",
-    "resolve_executor", "default_executor", "set_default_executor",
-    "register_executor", "available_executors", "aot",
+    "resolve_executor", "aot",
     "computation_key", "executor_compile_count", "executor_compile_log",
     "TrainConfig", "TrainHistory",
     "as_engine", "evaluate_fixed_action", "evaluate_policy", "train_policy",

@@ -51,9 +51,10 @@ construction. The computation key is derived from the `LowerableCall`
 value, so two tasks running the identical program share one dispatcher
 and one executable per shape.
 
-This module is solver-free (the engine and serving stack import it);
-selection mirrors the precision backends: explicit argument >
-`set_default_executor` > ``REPRO_SOLVE_EXECUTOR`` env var > ``"local"``.
+This module is solver-free (the engine and serving stack import it).
+Selection: an explicit argument (``"local"``, ``"sharded"`` or an
+instance) > the ``REPRO_SOLVE_EXECUTOR`` env var (the operator's
+deployment setting, `scripts/run_server.sh`) > ``"local"``.
 """
 from __future__ import annotations
 
@@ -566,56 +567,26 @@ def batch_callable(executor: "SolveExecutor", key,
 
 
 # ---------------------------------------------------------------------------
-# Registry + selection (mirrors precision.backend)
+# Selection
 # ---------------------------------------------------------------------------
 
 ExecutorLike = Union[None, str, SolveExecutor]
 
-_REGISTRY: Dict[str, Callable[[], SolveExecutor]] = {
+_NAMES: Dict[str, Callable[[], SolveExecutor]] = {
     "local": LocalExecutor,
     "sharded": ShardedExecutor,
 }
-_DEFAULT: Optional[SolveExecutor] = None
-
-
-def register_executor(name: str,
-                      factory: Callable[[], SolveExecutor]) -> None:
-    """Register an executor factory under `name` (overwrites allowed)."""
-    _REGISTRY[name] = factory
-
-
-def available_executors():
-    return sorted(_REGISTRY)
-
-
-def _from_name(name: str) -> SolveExecutor:
-    if name not in _REGISTRY:
-        raise KeyError(f"unknown solve executor {name!r}; "
-                       f"available: {available_executors()}")
-    return _REGISTRY[name]()
-
-
-def set_default_executor(executor: ExecutorLike) -> Optional[SolveExecutor]:
-    """Set the process-wide default executor (None restores env/'local'
-    resolution). Returns the previous override, for save/restore."""
-    global _DEFAULT
-    prev = _DEFAULT
-    _DEFAULT = (resolve_executor(executor)
-                if executor is not None else None)
-    return prev
-
-
-def default_executor() -> SolveExecutor:
-    if _DEFAULT is not None:
-        return _DEFAULT
-    return _from_name(os.environ.get(ENV_VAR, "local"))
 
 
 def resolve_executor(executor: ExecutorLike = None) -> SolveExecutor:
-    """Coerce an executor spec (instance | name | None=default) into an
-    executor instance. Pure Python — safe to call before tracing."""
+    """Coerce an executor spec (instance | name | None = the
+    ``REPRO_SOLVE_EXECUTOR`` env var, else "local") into an executor
+    instance. Pure Python — safe to call before tracing."""
     if executor is None:
-        return default_executor()
+        executor = os.environ.get(ENV_VAR, "local")
     if isinstance(executor, str):
-        return _from_name(executor)
+        if executor not in _NAMES:
+            raise KeyError(f"unknown solve executor {executor!r}; "
+                           f"available: {sorted(_NAMES)}")
+        return _NAMES[executor]()
     return executor

@@ -31,16 +31,14 @@ whose reduction is M/N-tile-invariant — measured), and `chop_trisolve`
 the same order). The multi-K-tile MXU schedule lives on as
 `kernels/qmatmul.qmatmul_op` outside the backend contract.
 
-Selection order: explicit argument > `set_default_backend` >
-``REPRO_PRECISION_BACKEND`` env var > the platform: ``"pallas"`` in a
-TPU process, ``"jnp"`` elsewhere. A TPU runs no f64 carrier
-(`precision.chop` refuses it while tracing), so the jnp backend serves
-there only on an f32 carrier.
+Selection: the platform chooses, ``"pallas"`` in a TPU process and
+``"jnp"`` elsewhere; a caller may still name a backend or pass an
+instance. A TPU runs no f64 carrier (`precision.chop` refuses it while
+tracing), so the jnp backend serves there only on an f32 carrier.
 """
 from __future__ import annotations
 
 import dataclasses
-import os
 from typing import Callable, Dict, Optional, Union
 
 import jax
@@ -48,8 +46,6 @@ import jax.numpy as jnp
 
 from . import chop as _chop
 from . import dw as _dw
-
-ENV_VAR = "REPRO_PRECISION_BACKEND"
 
 # Arrays smaller than this bypass the pallas chop kernel: the O(n) glue
 # vectors inside solver loops are launch-overhead-bound, and the two
@@ -284,34 +280,23 @@ def _as_dw(x):
 
 
 # ---------------------------------------------------------------------------
-# Registry + selection
+# Selection
 # ---------------------------------------------------------------------------
 
 BackendLike = Union[None, str, PrecisionBackend]
 
-_REGISTRY: Dict[str, Callable[[], PrecisionBackend]] = {
+_NAMES: Dict[str, Callable[[], PrecisionBackend]] = {
     "jnp": JnpBackend,
     "pallas": PallasBackend,
     "pallas-interpret": lambda: PallasBackend(interpret=True),
 }
-_DEFAULT: Optional[PrecisionBackend] = None
-
-
-def register_backend(name: str,
-                     factory: Callable[[], PrecisionBackend]) -> None:
-    """Register a backend factory under `name` (overwrites allowed)."""
-    _REGISTRY[name] = factory
-
-
-def available_backends():
-    return sorted(_REGISTRY)
 
 
 def _from_name(name: str) -> PrecisionBackend:
-    if name not in _REGISTRY:
+    if name not in _NAMES:
         raise KeyError(f"unknown precision backend {name!r}; "
-                       f"available: {available_backends()}")
-    backend = _REGISTRY[name]()
+                       f"available: {sorted(_NAMES)}")
+    backend = _NAMES[name]()
     if (isinstance(backend, PallasBackend) and not backend.interpret
             and jax.default_backend() != "tpu"):
         raise RuntimeError(
@@ -322,30 +307,13 @@ def _from_name(name: str) -> PrecisionBackend:
     return backend
 
 
-def set_default_backend(backend: BackendLike) -> Optional[PrecisionBackend]:
-    """Set the process-wide default backend (None restores env/platform
-    resolution). Returns the previous override, for save/restore."""
-    global _DEFAULT
-    prev = _DEFAULT
-    _DEFAULT = (resolve_backend(backend)
-                if backend is not None else None)
-    return prev
-
-
-def default_backend() -> PrecisionBackend:
-    if _DEFAULT is not None:
-        return _DEFAULT
-    name = os.environ.get(ENV_VAR) or (
-        "pallas" if jax.default_backend() == "tpu" else "jnp")
-    return _from_name(name)
-
-
 def resolve_backend(backend: BackendLike = None) -> PrecisionBackend:
-    """Coerce a backend spec (instance | name | None=default) into a
-    backend instance. Pure Python — call before tracing so the result
-    can be a static jit argument."""
+    """Coerce a backend spec (instance | name | None = the platform's:
+    "pallas" on a TPU, "jnp" elsewhere) into a backend instance. Pure
+    Python — call before tracing so the result can be a static jit
+    argument."""
     if backend is None:
-        return default_backend()
+        backend = "pallas" if jax.default_backend() == "tpu" else "jnp"
     if isinstance(backend, str):
         return _from_name(backend)
     return backend

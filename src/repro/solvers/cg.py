@@ -16,8 +16,9 @@ roles as GMRES-IR:
   u_g : CG working precision (matvec, preconditioner solves, dots)
   u_r : residual computation r_i = b - A x_i
 
-Stopping criteria mirror `ir.IRConfig` (Eqs. 14-16): update-norm
-convergence, stagnation, max outer iterations, explicit failure.
+The outer loop is GMRES-IR's own (`ir.refine`, Eqs. 14-16): update-norm
+convergence, stagnation, max outer iterations, explicit failure. This
+module holds the inner solve, `pcg`, and the entry points.
 
 Everything is jit-compatible with runtime format ids and vmappable over
 (systems x actions) — `cg_ir_batch` is the fixed-shape batched entry
@@ -33,13 +34,13 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.precision import resolve_backend, rounding_unit, tree_sum
+import numpy as np
+
+from repro.precision import resolve_backend, tree_sum
 
 from .blocking import DEFAULT_BLOCKING, BlockingPolicy, resolve_blocking
-from .carrier import carrier_norm, carrier_residual
-from .gmres import chop_mv
-from .ir import CONVERGED, FAILED, MAXITER, STAGNATED
-from .lu import lu_factor_auto
+from .carrier import carrier_norm
+from .ir import CONVERGED, FAILED, MAXITER, STAGNATED, ir_row
 from .triangular import lu_solve
 
 
@@ -67,10 +68,6 @@ class PCGResult(NamedTuple):
     z: jnp.ndarray             # solution update
     iters: jnp.ndarray         # inner iterations performed
     fail: jnp.ndarray          # breakdown (non-SPD curvature / non-finite)
-
-
-def _inf_norm(v):
-    return jnp.max(jnp.abs(v))
 
 
 def _dot(a, b, fmt_id, chop):
@@ -137,80 +134,15 @@ def pcg(A_g: jnp.ndarray, LU: jnp.ndarray, perm: jnp.ndarray,
     return PCGResult(z, j, fail)
 
 
-def _cg_ir_impl(A, b, x_true, action, cfg, backend) -> CGStats:
-    dtype = A.dtype
-    chop = backend.chop
-    uf, u, ug, ur = action[0], action[1], action[2], action[3]
-
-    lu = lu_factor_auto(A, uf, backend=backend, blocking=cfg.blocking)
-    A_g = chop(A, ug)
-    A_r = chop(A, ur)
-    b_r = chop(b, ur)
-    x0 = jnp.zeros_like(b)
-
-    u_work = rounding_unit(u, dtype)
-    conv_tol = jnp.maximum(jnp.asarray(cfg.tau, dtype), u_work)
-
-    def cond(state):
-        *_, done = state
-        return ~done
-
-    def body(state):
-        x, znorm_prev, i, n_cg, status, done = state
-        r = chop(b_r - chop_mv(A_r, x, ur, backend=backend), ur)
-        cg = pcg(A_g, lu.lu, lu.perm, r, ug, m_max=cfg.m_max,
-                 tol=cfg.tol_inner, backend=backend,
-                 blocking=cfg.blocking)
-        z = chop(cg.z, u)
-        x_new = chop(x + z, u)
-        znorm = _inf_norm(z)
-        xnorm = _inf_norm(x_new)
-        i_new = i + 1
-
-        converged = znorm <= conv_tol * xnorm
-        stagnated = (i > 0) & (znorm >= cfg.stag_tol * znorm_prev)
-        hit_max = i_new >= cfg.i_max
-        failed = cg.fail | ~jnp.all(jnp.isfinite(x_new))
-
-        status = jnp.where(
-            failed, FAILED,
-            jnp.where(converged, CONVERGED,
-                      jnp.where(stagnated, STAGNATED,
-                                jnp.where(hit_max, MAXITER, status))))
-        done = converged | stagnated | hit_max | failed
-        x_new = jnp.where(failed, x, x_new)
-        return (x_new, znorm, i_new, n_cg + cg.iters, status, done)
-
-    init_state = (x0, jnp.asarray(jnp.inf, dtype), jnp.int32(0),
-                  jnp.int32(0), jnp.int32(MAXITER), lu.fail)
-    x, _, n_outer, n_cg, status, _ = lax.while_loop(cond, body, init_state)
-    status = jnp.where(lu.fail, FAILED, status)
-
-    # Final metrics in the carrier (true fp64), Eq. 17, with the
-    # executor-invariant residual schedule (ir.carrier_residual).
-    res = carrier_residual(A, b, x)
-    res_norm = _inf_norm(res)
-    normA = jnp.max(tree_sum(jnp.abs(A), axis=1))
-    ferr = _inf_norm(x - x_true) / _inf_norm(x_true)
-    nbe = res_norm / (normA * _inf_norm(x) + _inf_norm(b))
-    ferr = jnp.where(jnp.isfinite(ferr), ferr, jnp.asarray(jnp.inf, dtype))
-    nbe = jnp.where(jnp.isfinite(nbe), nbe, jnp.asarray(jnp.inf, dtype))
-    return CGStats(ferr, nbe, n_outer, n_cg, status, res_norm)
-
-
 # Backend resolved before tracing, passed value-hashed static: one
 # executable per (shapes, cfg, backend), format ids runtime data
-# (DESIGN.md §3.4, §6.3). Module-level jits so tests can assert the
-# compile-cache stays at one across precision actions.
-_cg_ir_jit = partial(jax.jit, static_argnames=("cfg", "backend"))(
-    _cg_ir_impl)
-
-
+# (DESIGN.md §3.4, §6.3). A module-level jit so tests can assert the
+# compile-cache stays at one across precision actions. The outer loop
+# is `ir.refine`; `pcg` is the step's inner solve.
 @partial(jax.jit, static_argnames=("cfg", "backend"))
 def _cg_ir_batch_jit(A, b, x_true, actions, cfg, backend) -> CGStats:
-    return jax.vmap(lambda Ai, bi, xi, ai:
-                    _cg_ir_impl(Ai, bi, xi, ai, cfg, backend)
-                    )(A, b, x_true, actions)
+    return CGStats(*jax.vmap(lambda Ai, bi, xi, ai: ir_row(
+        pcg, Ai, bi, xi, ai, cfg, backend))(A, b, x_true, actions))
 
 
 def cg_ir(A: jnp.ndarray, b: jnp.ndarray, x_true: jnp.ndarray,
@@ -220,20 +152,27 @@ def cg_ir(A: jnp.ndarray, b: jnp.ndarray, x_true: jnp.ndarray,
 
     A: (n, n) carrier (SPD; float64 on the host, f32 when the pallas
     backend coerces); action: int32[4] runtime format ids. `backend`
-    selects the precision backend (DESIGN.md §6)."""
-    bk = resolve_backend(backend)
+    selects the precision backend (DESIGN.md §6). Runs as a batch of one
+    through `cg_ir_batch`."""
+    out = cg_ir_batch(*(np.asarray(a)[None]
+                        for a in (A, b, x_true, action)),
+                      cfg=cfg, backend=backend)
+    return CGStats(*(f[0] for f in out))
+
+
+def _prepare(bk, A, b, x_true, actions):
+    """The batch's operands for `_cg_ir_batch_jit`, cast to the
+    backend's carrier outside the jit boundary."""
     A, b, x_true = bk.coerce(jnp.asarray(A), jnp.asarray(b),
                              jnp.asarray(x_true))
-    return _cg_ir_jit(A, b, x_true, action, cfg, bk)
+    return A, b, x_true, jnp.asarray(actions)
 
 
 def cg_ir_batch(A, b, x_true, actions, cfg: CGConfig = CGConfig(),
                 backend=None) -> CGStats:
     """Batched (vmap) CG-IR: one fixed-shape chunk = one call."""
     bk = resolve_backend(backend)
-    A, b, x_true = bk.coerce(jnp.asarray(A), jnp.asarray(b),
-                             jnp.asarray(x_true))
-    return _cg_ir_batch_jit(A, b, x_true, actions, cfg, bk)
+    return _cg_ir_batch_jit(*_prepare(bk, A, b, x_true, actions), cfg, bk)
 
 
 def cg_ir_batch_lowerable(cfg: CGConfig = CGConfig(), backend=None):
@@ -242,14 +181,9 @@ def cg_ir_batch_lowerable(cfg: CGConfig = CGConfig(), backend=None):
     for cross-task executable dedupe (DESIGN.md §12)."""
     from repro.core.executor import LowerableCall
     bk = resolve_backend(backend)
-
-    def prepare(A, b, x_true, actions):
-        A, b, x_true = bk.coerce(jnp.asarray(A), jnp.asarray(b),
-                                 jnp.asarray(x_true))
-        return A, b, x_true, jnp.asarray(actions)
-
     return LowerableCall(_cg_ir_batch_jit,
-                         (("cfg", cfg), ("backend", bk)), prepare)
+                         (("cfg", cfg), ("backend", bk)),
+                         partial(_prepare, bk))
 
 
 # Re-exported status codes (shared convention with ir.py / core.task).

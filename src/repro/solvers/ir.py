@@ -11,13 +11,14 @@ Stopping criteria (paper Eqs. 14-16):
   stagnated : ||z_i||_inf / ||z_{i-1}||_inf >= stag_tol
   max-iter  : i >= i_max
 plus an explicit failure path (LU overflow / zero pivot / non-finite GMRES).
+`refine` is that loop, once, for GMRES-IR on both carriers and for CG-IR
+(`cg.py`); each solver hands it one refinement step.
 
-x0 initialization: the paper's Alg. 2 line 2 uses x0 = U\\(L\\b); its
+x0 = 0: the paper's Alg. 2 line 2 uses x0 = U\\(L\\b), but its
 *reported* iteration counts (exactly 2.0 outer iterations for every FP64
 baseline row of Tables 2/4/6) are only consistent with x0 = 0, where the
 first "refinement" performs the initial solve through the preconditioned
-GMRES. We default to x0 = 0 to match the paper's accounting and provide
-init="lu" for the literal Alg. 2 variant.
+GMRES.
 
 Everything is jit-compatible with runtime format ids and vmappable over
 (systems x actions) — the bandit sweeps a whole episode in one batched call.
@@ -41,7 +42,6 @@ from .blocking import DEFAULT_BLOCKING, BlockingPolicy
 from .carrier import carrier_residual, carrier_residual_dw
 from .gmres import chop_mv, gmres_precond, gmres_precond_dw
 from .lu import LUFactors, lu_factor_auto, lu_factor_dw
-from .triangular import lu_solve
 
 
 @dataclasses.dataclass(frozen=True)
@@ -51,7 +51,6 @@ class IRConfig:
     m_max: int = 40            # max inner GMRES iterations
     tol_inner: float = 1e-4    # GMRES relative residual tolerance
     stag_tol: float = 0.9      # Eq. 15 stagnation threshold
-    init: str = "zero"         # "zero" (paper accounting) | "lu" (Alg.2 l.2)
     # Blocked LU/trisolve engagement (DESIGN.md §6.4). Part of the
     # frozen config so it rides in the static jit key with the rest.
     blocking: BlockingPolicy = DEFAULT_BLOCKING
@@ -74,7 +73,82 @@ def _inf_norm(v):
     return jnp.max(jnp.abs(v))
 
 
-def _gmres_ir_impl(A, b, x_true, action, cfg, backend) -> SolveStats:
+def _lead(v):
+    """The leading word of a carrier value: `v.hi` for a `dw.DW`, `v`
+    otherwise. The loop's norms and finiteness tests read it."""
+    return v.hi if isinstance(v, dw.DW) else v
+
+
+def refine(step, x0, lu_fail, conv_tol, cfg):
+    """Alg. 2's outer loop, shared by every solver and carrier.
+
+    `step(x)` is one refinement iteration: residual, inner solve and
+    update, returning `(z, x_new, iters, fail)`. This loop owns the
+    rest: the carry `(x, ||z||_prev, i, inner iters, status, done)`, the
+    stopping rules of Eqs. 14-16, the failure rule (an inner failure or
+    a non-finite x_new keeps x), the status cascade FAILED > CONVERGED >
+    STAGNATED > MAXITER, and the LU-failure override. `x0` may be an
+    array or a `dw.DW` pair; `lu_fail` skips the loop. Returns
+    `(x, n_outer, n_inner, status)`."""
+
+    def cond(state):
+        *_, done = state
+        return ~done
+
+    def body(state):
+        x, znorm_prev, i, n_inner, status, done = state
+        z, x_new, iters, fail = step(x)
+        znorm = _inf_norm(_lead(z))
+        xnorm = _inf_norm(_lead(x_new))
+        i_new = i + 1
+
+        converged = znorm <= conv_tol * xnorm
+        stagnated = (i > 0) & (znorm >= cfg.stag_tol * znorm_prev)
+        hit_max = i_new >= cfg.i_max
+        failed = fail | ~jnp.all(jnp.isfinite(_lead(x_new)))
+
+        status = jnp.where(
+            failed, FAILED,
+            jnp.where(converged, CONVERGED,
+                      jnp.where(stagnated, STAGNATED,
+                                jnp.where(hit_max, MAXITER, status))))
+        done = converged | stagnated | hit_max | failed
+        x_new = jax.tree_util.tree_map(
+            lambda old, new: jnp.where(failed, old, new), x, x_new)
+        return (x_new, znorm, i_new, n_inner + iters, status, done)
+
+    init_state = (x0, jnp.asarray(jnp.inf, conv_tol.dtype), jnp.int32(0),
+                  jnp.int32(0), jnp.int32(MAXITER), lu_fail)
+    x, _, n_outer, n_inner, status, _ = lax.while_loop(cond, body,
+                                                       init_state)
+    return x, n_outer, n_inner, jnp.where(lu_fail, FAILED, status)
+
+
+def solve_stats(A, b, x, x_true, n_outer, n_inner, status) -> SolveStats:
+    """The Eq. 17 epilogue in the carrier, with the executor-invariant
+    residual schedule (see carrier_residual). On the double-word carrier
+    the residual and the error are DW, so nbe and ferr have no f32
+    floor; the norms read the leading words. A non-finite ferr or nbe
+    reads inf."""
+    if isinstance(x, dw.DW):
+        res, err = carrier_residual_dw(A, b, x), dw.sub(x, x_true)
+    else:
+        res, err = carrier_residual(A, b, x), x - x_true
+    A, b, x, x_true = _lead(A), _lead(b), _lead(x), _lead(x_true)
+    res_norm = _inf_norm(_lead(res))
+    normA = jnp.max(tree_sum(jnp.abs(A), axis=1))
+    ferr = _inf_norm(_lead(err)) / _inf_norm(x_true)
+    nbe = res_norm / (normA * _inf_norm(x) + _inf_norm(b))
+    inf = jnp.asarray(jnp.inf, x.dtype)
+    ferr = jnp.where(jnp.isfinite(ferr), ferr, inf)
+    nbe = jnp.where(jnp.isfinite(nbe), nbe, inf)
+    return SolveStats(ferr, nbe, n_outer, n_inner, status, res_norm)
+
+
+def ir_row(inner, A, b, x_true, action, cfg, backend) -> SolveStats:
+    """One row of IR on a plain carrier: LU at u_f, then `refine` with
+    the step r = b - A x at u_r, z from `inner` (`gmres_precond` or
+    `cg.pcg`) at u_g preconditioned by the LU, x + z at u. x0 = 0."""
     dtype = A.dtype
     chop = backend.chop
     uf, u, ug, ur = action[0], action[1], action[2], action[3]
@@ -83,63 +157,19 @@ def _gmres_ir_impl(A, b, x_true, action, cfg, backend) -> SolveStats:
     A_g = chop(A, ug)
     A_r = chop(A, ur)
     b_r = chop(b, ur)
+    conv_tol = jnp.maximum(jnp.asarray(cfg.tau, dtype),
+                           rounding_unit(u, dtype))
 
-    if cfg.init == "lu":
-        x0 = lu_solve(lu.lu, lu.perm, b, uf, backend=backend,
-                      blocking=cfg.blocking)
-        x0 = jnp.where(jnp.isfinite(x0), x0, jnp.zeros_like(x0))
-    else:
-        x0 = jnp.zeros_like(b)
-
-    u_work = rounding_unit(u, dtype)
-    conv_tol = jnp.maximum(jnp.asarray(cfg.tau, dtype), u_work)
-
-    def cond(state):
-        *_, done = state
-        return ~done
-
-    def body(state):
-        x, znorm_prev, i, n_gmres, status, done = state
+    def step(x):
         r = chop(b_r - chop_mv(A_r, x, ur, backend=backend), ur)
-        gm = gmres_precond(A_g, lu.lu, lu.perm, r, ug,
-                           m_max=cfg.m_max, tol=cfg.tol_inner,
-                           backend=backend, blocking=cfg.blocking)
-        z = chop(gm.z, u)
-        x_new = chop(x + z, u)
-        znorm = _inf_norm(z)
-        xnorm = _inf_norm(x_new)
-        i_new = i + 1
+        sol = inner(A_g, lu.lu, lu.perm, r, ug, m_max=cfg.m_max,
+                    tol=cfg.tol_inner, backend=backend,
+                    blocking=cfg.blocking)
+        z = chop(sol.z, u)
+        return z, chop(x + z, u), sol.iters, sol.fail
 
-        converged = znorm <= conv_tol * xnorm
-        stagnated = (i > 0) & (znorm >= cfg.stag_tol * znorm_prev)
-        hit_max = i_new >= cfg.i_max
-        failed = gm.fail | ~jnp.all(jnp.isfinite(x_new))
-
-        status = jnp.where(
-            failed, FAILED,
-            jnp.where(converged, CONVERGED,
-                      jnp.where(stagnated, STAGNATED,
-                                jnp.where(hit_max, MAXITER, status))))
-        done = converged | stagnated | hit_max | failed
-        x_new = jnp.where(failed, x, x_new)
-        return (x_new, znorm, i_new, n_gmres + gm.iters, status, done)
-
-    init_state = (x0, jnp.asarray(jnp.inf, dtype), jnp.int32(0),
-                  jnp.int32(0), jnp.int32(MAXITER), lu.fail)
-    x, _, n_outer, n_gmres, status, _ = lax.while_loop(cond, body, init_state)
-    status = jnp.where(lu.fail, FAILED, status)
-
-    # Final metrics in the carrier (true fp64), Eq. 17, with the
-    # executor-invariant residual schedule (see carrier_residual).
-    res = carrier_residual(A, b, x)
-    res_norm = _inf_norm(res)
-    normA = jnp.max(tree_sum(jnp.abs(A), axis=1))
-    ferr = _inf_norm(x - x_true) / _inf_norm(x_true)
-    nbe = res_norm / (normA * _inf_norm(x) + _inf_norm(b))
-    bad = ~jnp.isfinite(ferr)
-    ferr = jnp.where(bad, jnp.asarray(jnp.inf, dtype), ferr)
-    nbe = jnp.where(jnp.isfinite(nbe), nbe, jnp.asarray(jnp.inf, dtype))
-    return SolveStats(ferr, nbe, n_outer, n_gmres, status, res_norm)
+    x, *counts = refine(step, jnp.zeros_like(b), lu.fail, conv_tol, cfg)
+    return solve_stats(A, b, x, x_true, *counts)
 
 
 FP64_ID = FORMAT_ID["fp64"]
@@ -187,12 +217,7 @@ def _gmres_ir_dw_row(A, b, x_true, action, LU, perm, lu_fail, dw_gmres,
     def no_dw_solve(r):
         return zero, jnp.int32(0), jnp.asarray(True)
 
-    def cond(state):
-        *_, done = state
-        return ~done
-
-    def body(state):
-        x, znorm_prev, i, n_gmres, status, done = state
+    def step(x):
         r = dw.chop(dw.sub(b_r, dw.chop(dw.matvec(A_r, x, sA_r), ur)), ur)
         # Rows at u_g = fp64 hand f32 GMRES a zero residual, the others
         # hand it to the DW GMRES: each solver stops at once on those.
@@ -201,45 +226,12 @@ def _gmres_ir_dw_row(A, b, x_true, action, LU, perm, lu_fail, dw_gmres,
             m_max=cfg.m_max, tol=cfg.tol_inner, backend=base,
             blocking=cfg.blocking)
         zd, itd, faild = lax.cond(dw_gmres, dw_solve, no_dw_solve, r)
-        z = dw.where(g64, zd, dw.lift(gm.z))
-        iters = jnp.where(g64, itd, gm.iters)
-        gfail = jnp.where(g64, faild, gm.fail)
-        z = dw.chop(z, u)
-        x_new = dw.chop(dw.add(x, z), u)
-        znorm = _inf_norm(z.hi)
-        xnorm = _inf_norm(x_new.hi)
-        i_new = i + 1
+        z = dw.chop(dw.where(g64, zd, dw.lift(gm.z)), u)
+        return (z, dw.chop(dw.add(x, z), u), jnp.where(g64, itd, gm.iters),
+                jnp.where(g64, faild, gm.fail))
 
-        converged = znorm <= conv_tol * xnorm
-        stagnated = (i > 0) & (znorm >= cfg.stag_tol * znorm_prev)
-        hit_max = i_new >= cfg.i_max
-        failed = gfail | ~jnp.all(jnp.isfinite(x_new.hi))
-
-        status = jnp.where(
-            failed, FAILED,
-            jnp.where(converged, CONVERGED,
-                      jnp.where(stagnated, STAGNATED,
-                                jnp.where(hit_max, MAXITER, status))))
-        done = converged | stagnated | hit_max | failed
-        x_new = dw.where(failed, x, x_new)
-        return (x_new, znorm, i_new, n_gmres + iters, status, done)
-
-    init_state = (zero, jnp.asarray(jnp.inf, f32), jnp.int32(0),
-                  jnp.int32(0), jnp.int32(MAXITER), lu_fail)
-    x, _, n_outer, n_gmres, status, _ = lax.while_loop(cond, body,
-                                                       init_state)
-    status = jnp.where(lu_fail, FAILED, status)
-
-    # Eq. 17 on the DW carrier: the residual and the error in DW, so
-    # nbe and ferr have no f32 floor; the norms read the high words.
-    res_norm = _inf_norm(carrier_residual_dw(A, b, x).hi)
-    normA = jnp.max(tree_sum(jnp.abs(A.hi), axis=1))
-    ferr = _inf_norm(dw.sub(x, x_true).hi) / _inf_norm(x_true.hi)
-    nbe = res_norm / (normA * _inf_norm(x.hi) + _inf_norm(b.hi))
-    inf = jnp.asarray(jnp.inf, f32)
-    ferr = jnp.where(jnp.isfinite(ferr), ferr, inf)
-    nbe = jnp.where(jnp.isfinite(nbe), nbe, inf)
-    return SolveStats(ferr, nbe, n_outer, n_gmres, status, res_norm)
+    x, *counts = refine(step, zero, lu_fail, conv_tol, cfg)
+    return solve_stats(A, b, x, x_true, *counts)
 
 
 def _gmres_ir_dw_batch(A, b, x_true, actions, cfg, bk) -> SolveStats:
@@ -250,9 +242,6 @@ def _gmres_ir_dw_batch(A, b, x_true, actions, cfg, bk) -> SolveStats:
     `lu_factor_dw`, one row at a time under its own cond, so only those
     rows pay for it. Both flush predicates are computed here, outside
     the vmap, so each `lax.cond` stays a cond."""
-    if cfg.init != "zero":
-        raise ValueError("the double-word carrier starts from x0 = 0 "
-                         f"(IRConfig.init='zero'), not {cfg.init!r}")
     base = bk.base
     A, b, x_true = dw.unstack(A), dw.unstack(b), dw.unstack(x_true)
     dw_lu, dw_gmres = dw_branches(actions)
@@ -292,19 +281,14 @@ def _is_dw(backend) -> bool:
 # The backend is resolved *before* tracing and passed as a value-hashed
 # static argument: one executable per (shapes, cfg, backend), with the
 # action's format ids still runtime data (DESIGN.md §3.4, §6.3). The
-# jitted inner functions are module-level so tests can assert their
+# jitted entry point is module-level so tests can assert its
 # compile-cache size stays at one across precision actions.
-_gmres_ir_jit = partial(jax.jit, static_argnames=("cfg", "backend"))(
-    _gmres_ir_impl)
-
-
 @partial(jax.jit, static_argnames=("cfg", "backend"))
 def _gmres_ir_batch_jit(A, b, x_true, actions, cfg, backend) -> SolveStats:
     if _is_dw(backend):
         return _gmres_ir_dw_batch(A, b, x_true, actions, cfg, backend)
-    return jax.vmap(lambda Ai, bi, xi, ai:
-                    _gmres_ir_impl(Ai, bi, xi, ai, cfg, backend)
-                    )(A, b, x_true, actions)
+    return jax.vmap(lambda Ai, bi, xi, ai: ir_row(
+        gmres_precond, Ai, bi, xi, ai, cfg, backend))(A, b, x_true, actions)
 
 
 def gmres_ir(A: jnp.ndarray, b: jnp.ndarray, x_true: jnp.ndarray,
@@ -315,65 +299,55 @@ def gmres_ir(A: jnp.ndarray, b: jnp.ndarray, x_true: jnp.ndarray,
     A: (n, n) carrier (float64 for the paper's host experiments; the
     pallas backend coerces to its f32 TPU carrier); action: int32[4]
     runtime format ids. `backend` selects the precision backend
-    (DESIGN.md §6): an instance, a registry name, or None = default.
+    (DESIGN.md §6): an instance, a name, or None = the platform's. Runs
+    as a batch of one through `gmres_ir_batch`.
     """
-    bk = resolve_backend(backend)
+    out = gmres_ir_batch(*(np.asarray(a)[None]
+                           for a in (A, b, x_true, action)),
+                         cfg=cfg, backend=backend)
+    return SolveStats(*(f[0] for f in out))
+
+
+def _prepare(bk, A, b, x_true, actions):
+    """The batch's operands for `_gmres_ir_batch_jit`, outside the jit
+    boundary. On the double-word carrier, float64 rows are split into
+    (hi, lo) f32 pairs by `dw.stack_host`, in numpy when they are host
+    arrays, so the transfer carries no f64; rows already split (one axis
+    more than a batch of matrices) pass as they are. Elsewhere `coerce`
+    casts to the backend's carrier."""
     if _is_dw(bk):
-        out = gmres_ir_batch(*(np.asarray(a)[None]
-                               for a in (A, b, x_true, action)),
-                             cfg=cfg, backend=bk)
-        return SolveStats(*(f[0] for f in out))
+        if np.ndim(A) == 3:
+            A, b, x_true = (dw.stack_host(a) for a in (A, b, x_true))
+        return (jnp.asarray(A), jnp.asarray(b), jnp.asarray(x_true),
+                jnp.asarray(actions))
     A, b, x_true = bk.coerce(jnp.asarray(A), jnp.asarray(b),
                              jnp.asarray(x_true))
-    return _gmres_ir_jit(A, b, x_true, action, cfg, bk)
+    return A, b, x_true, jnp.asarray(actions)
 
 
 def gmres_ir_batch(A, b, x_true, actions, cfg: IRConfig = IRConfig(),
                    backend=None) -> SolveStats:
     """Batched (vmap) GMRES-IR: one episode sweep = one call."""
     bk = resolve_backend(backend)
-    if _is_dw(bk):
-        return _gmres_ir_batch_jit(*_dw_args(A, b, x_true, actions),
-                                   cfg, bk)
-    A, b, x_true = bk.coerce(jnp.asarray(A), jnp.asarray(b),
-                             jnp.asarray(x_true))
-    return _gmres_ir_batch_jit(A, b, x_true, actions, cfg, bk)
-
-
-def _dw_args(A, b, x_true, actions):
-    """The batch's operands on the double-word carrier, outside the jit
-    boundary: float64 rows split into (hi, lo) f32 pairs by
-    `dw.stack_host`, in numpy when they are host arrays, so the transfer
-    carries no f64. Rows already split (one axis more than a batch of
-    matrices) pass as they are."""
-    if np.ndim(A) == 3:
-        A, b, x_true = (dw.stack_host(a) for a in (A, b, x_true))
-    return (jnp.asarray(A), jnp.asarray(b), jnp.asarray(x_true),
-            jnp.asarray(actions))
+    return _gmres_ir_batch_jit(*_prepare(bk, A, b, x_true, actions),
+                               cfg, bk)
 
 
 def gmres_ir_batch_lowerable(cfg: IRConfig = IRConfig(), backend=None):
     """`gmres_ir_batch` in `core.executor.LowerableCall` form: the same
-    eager carrier coercion (`prepare`) around the same module-level
+    eager carrier coercion (`_prepare`) around the same module-level
     jitted entry point, but AOT-compilable — `lower().compile()` per
     shape — and value-keyed by (entry point, cfg, backend), so every
     task and call site running this program shares one executable per
     shape (DESIGN.md §12).
 
-    `prepare` takes rows in `bk.host_dtype`, the dtype the flush path
-    stacks them in (`tasks.base.stack_fixed`): f32 on the Pallas
-    backend, so `coerce` casts nothing and no f64 buffer reaches the
-    device; float64 on `DoubleWord`, split here into (hi, lo) f32
-    pairs; as given on the jnp backend."""
+    It takes rows in `bk.host_dtype`, the dtype the flush path stacks
+    them in (`tasks.base.stack_fixed`): f32 on the Pallas backend, so
+    `coerce` casts nothing and no f64 buffer reaches the device; float64
+    on `DoubleWord`, split into (hi, lo) f32 pairs; as given on the jnp
+    backend."""
     from repro.core.executor import LowerableCall
     bk = resolve_backend(backend)
-
-    def prepare(A, b, x_true, actions):
-        if _is_dw(bk):
-            return _dw_args(A, b, x_true, actions)
-        A, b, x_true = bk.coerce(jnp.asarray(A), jnp.asarray(b),
-                                 jnp.asarray(x_true))
-        return A, b, x_true, jnp.asarray(actions)
-
     return LowerableCall(_gmres_ir_batch_jit,
-                         (("cfg", cfg), ("backend", bk)), prepare)
+                         (("cfg", cfg), ("backend", bk)),
+                         partial(_prepare, bk))

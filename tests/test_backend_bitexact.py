@@ -356,8 +356,6 @@ def test_tpu_process_defaults_to_compiled_pallas(monkeypatch):
     serves the compiled pallas kernels (never the interpreter)."""
     import jax
 
-    from repro.precision import backend as B
-    monkeypatch.delenv(B.ENV_VAR, raising=False)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     bk = resolve_backend(None)
     assert isinstance(bk, PallasBackend) and not bk.interpret
@@ -384,11 +382,20 @@ def test_f64_carrier_refused_on_tpu(monkeypatch):
 
 @pytest.mark.fast
 def test_env_var_selects_default(monkeypatch):
-    from repro.precision import backend as B
-    monkeypatch.setenv(B.ENV_VAR, "pallas-interpret")
-    assert resolve_backend(None).name == "pallas"
-    monkeypatch.setenv(B.ENV_VAR, "jnp")
-    assert resolve_backend(None).name == "jnp"
+    """No environment variable selects the precision backend: where none
+    is named, the platform does (a CPU here, so "jnp"); a name or an
+    instance still resolves as given."""
+    import jax
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    monkeypatch.setenv("REPRO_SOLVE_EXECUTOR", "sharded")
+    assert resolve_backend(None) == JnpBackend()
+    assert resolve_backend("pallas-interpret") == PallasBackend(
+        interpret=True)
+    assert resolve_backend("jnp") == JnpBackend()
+    inst = PallasBackend(interpret=True, chop_min_elems=1)
+    assert resolve_backend(inst) is inst
+    with pytest.raises(KeyError, match="nope"):
+        resolve_backend("nope")
 
 
 @pytest.mark.fast

@@ -20,9 +20,9 @@ import sys
 import numpy as np
 import pytest
 
-from repro.core import (LocalExecutor, ShardedExecutor, available_executors,
-                        pad_to_bucket, reduced_action_space, resolve_executor,
-                        set_default_executor, solve_fixed_batch)
+from repro.core import (LocalExecutor, ShardedExecutor, pad_to_bucket,
+                        reduced_action_space, resolve_executor,
+                        solve_fixed_batch)
 from repro.core.engine import AutotuneEngine
 from repro.data.matrices import randsvd_dense
 from repro.service import AutotuneServer, BatcherConfig, MicroBatcher
@@ -54,22 +54,35 @@ def test_preferred_chunk_rounding():
 
 @pytest.mark.fast
 def test_registry_and_selection(monkeypatch):
+    """What selects an executor: a name, an instance, else the
+    operator's `REPRO_SOLVE_EXECUTOR`, else "local"; the precision
+    backend is the platform's unless named."""
+    import jax
+
     from repro.core import executor as E
-    assert "local" in available_executors()
-    assert "sharded" in available_executors()
-    assert resolve_executor(None).name == "local"
+    from repro.precision import PallasBackend, resolve_backend
+    monkeypatch.delenv(E.ENV_VAR, raising=False)
+    assert resolve_executor(None) == LocalExecutor()
     assert resolve_executor("local") == LocalExecutor()
+    assert resolve_executor("sharded") == ShardedExecutor()
     inst = ShardedExecutor(data=1)
     assert resolve_executor(inst) is inst
-    with pytest.raises(KeyError):
+    with pytest.raises(KeyError, match="nope"):
         resolve_executor("nope")
     monkeypatch.setenv(E.ENV_VAR, "sharded")
     assert resolve_executor(None).name == "sharded"
-    prev = set_default_executor("local")
-    try:
-        assert resolve_executor(None).name == "local"   # beats env var
-    finally:
-        set_default_executor(prev)
+    assert resolve_executor("local").name == "local"    # a name beats it
+    assert resolve_executor(inst) is inst
+    monkeypatch.setenv(E.ENV_VAR, "nope")
+    with pytest.raises(KeyError, match="nope"):
+        resolve_executor(None)
+    # The platform chooses the precision backend, whatever the env says.
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    assert resolve_backend(None).name == "jnp"
+    with pytest.raises(RuntimeError, match="pallas-interpret"):
+        resolve_backend("pallas")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert resolve_backend(None) == PallasBackend()
 
 
 @pytest.mark.fast

@@ -154,15 +154,15 @@ def test_double_word_rows_reach_the_split_as_float64(monkeypatch):
     cfg = _ir(1.4e-6)
     rows, acts = _rows()
     seen = []
-    dw_args = ir._dw_args
+    prepare = ir._prepare
 
-    def spy(A, b, x_true, actions):
+    def spy(bk_, A, b, x_true, actions):
         seen.append((A.dtype, b.dtype, x_true.dtype))
         lo = dw.stack_host(A)[:, 1]
         assert np.count_nonzero(lo[: len(rows)]) > 0   # low words kept
-        return dw_args(A, b, x_true, actions)
+        return prepare(bk_, A, b, x_true, actions)
 
-    monkeypatch.setattr(ir, "_dw_args", spy)
+    monkeypatch.setattr(ir, "_prepare", spy)
     recs = _solve(rows, acts, cfg, bk)
     assert seen == [(np.float64,) * 3]
     stats = _device_cast(rows, acts, cfg, bk)

@@ -1,4 +1,6 @@
-"""Solver substrate tests: LU, triangular, GMRES, GMRES-IR."""
+"""Solver substrate tests: LU, triangular, GMRES, GMRES-IR, and the
+refinement loop GMRES-IR and CG-IR share."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -6,11 +8,11 @@ import scipy.linalg as sla
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
-from repro.precision import FORMAT_ID, FORMATS
-from repro.solvers import (CONVERGED, FAILED, IRConfig, STAGNATED, gmres_ir,
-                           gmres_ir_batch, gmres_precond, lu_factor,
-                           lu_factor_blocked, lu_solve, solve_unit_lower,
-                           solve_upper)
+from repro.precision import FORMAT_ID, FORMATS, DoubleWord, JnpBackend, dw
+from repro.solvers import (CONVERGED, FAILED, MAXITER, CGConfig, IRConfig,
+                           STAGNATED, cg, gmres_ir, gmres_ir_batch,
+                           gmres_precond, ir, lu_factor, lu_factor_blocked,
+                           lu_solve, solve_unit_lower, solve_upper)
 
 RNG = np.random.default_rng(42)
 FP64 = FORMAT_ID["fp64"]
@@ -229,3 +231,117 @@ def test_prop_monotone_precision_error(n, kappa):
     # non-converged low-precision runs must not be better than fp64.
     assert errs[0] >= errs[2] * 0.01
     assert errs[1] >= errs[2] * 0.01
+
+
+# ---------------------------------------------------------------------------
+# The one refinement loop (Alg. 2, Eqs. 14-16) under each step
+# ---------------------------------------------------------------------------
+
+# Each solver's batch entry point with its carrier, called unjitted so
+# the spy below is traced into this call's program and no other.
+LOOP_SOLVERS = {
+    "gmres-jnp": (ir._gmres_ir_batch_jit, ir._prepare, JnpBackend(),
+                  IRConfig(tau=1e-10, i_max=6, m_max=24)),
+    "gmres-dw": (ir._gmres_ir_batch_jit, ir._prepare,
+                 DoubleWord(JnpBackend()),
+                 IRConfig(tau=1e-10, i_max=6, m_max=24)),
+    "cg-jnp": (cg._cg_ir_batch_jit, cg._prepare, JnpBackend(),
+               CGConfig(tau=1e-10, i_max=6, m_max=30)),
+}
+# (arm, kappa, zero column, poison the second step's x_new with NaN,
+# the statuses the case may end in)
+LOOP_CASES = {
+    "converged": ([FP64] * 4, 1e2, False, False, {CONVERGED}),
+    "stagnated_or_maxiter": ([BF16, FP64, TF32, FP64], 1e6, False, False,
+                             {STAGNATED, MAXITER}),
+    "lu_fails": ([FP64] * 4, 1e2, True, False, {FAILED}),
+    "step_fails": ([FP64] * 4, 1e2, False, True, {FAILED}),
+}
+
+
+def _loop_system(solver, kappa, zero_col, n=24):
+    rng = np.random.default_rng(int(np.log10(kappa)) + 7 * zero_col)
+    if solver.startswith("cg"):
+        q, _ = np.linalg.qr(rng.standard_normal((n, n)))
+        A = (q * np.logspace(0, -np.log10(kappa), n)) @ q.T
+        A, x = (A + A.T) / 2, rng.standard_normal(n)
+    else:
+        A, _, x = rand_system(n, kappa=kappa, rng=rng)
+    if zero_col:
+        A[:, n // 2] = 0.0
+    return A, A @ x, x
+
+
+def _flat(tree):
+    return [np.asarray(v).reshape(-1) for v in jax.tree_util.tree_leaves(
+        tree)]
+
+
+def _scalar(v):
+    return np.asarray(v).reshape(-1)[0]
+
+
+@pytest.mark.parametrize("case", sorted(LOOP_CASES))
+@pytest.mark.parametrize("solver", sorted(LOOP_SOLVERS))
+def test_refinement_loop_follows_eqs_14_16(solver, case, monkeypatch):
+    """GMRES-IR on both carriers and CG-IR run one outer loop
+    (`ir.refine`): replayed from the steps it took, its status and
+    n_outer follow Eqs. 14-16 with the cascade FAILED > CONVERGED >
+    STAGNATED > MAXITER, an LU failure skips the loop, and a failed step
+    (here x_new poisoned to NaN) keeps x."""
+    entry, prepare, bk, cfg = LOOP_SOLVERS[solver]
+    arm, kappa, zero_col, poison, ends_in = LOOP_CASES[case]
+    steps, ends = [], []
+    refine = ir.refine
+
+    def spy(step, x0, lu_fail, conv_tol, cfg_):
+        def logged(x):
+            z, x_new, iters, fail = step(x)
+            if poison:
+                x_new = jax.tree_util.tree_map(lambda v: jnp.where(
+                    jnp.any(ir._lead(x) != 0), jnp.nan, v), x_new)
+            jax.debug.callback(lambda *a: steps.append(a), x, z, x_new,
+                               fail, conv_tol)
+            return z, x_new, iters, fail
+
+        out = refine(logged, x0, lu_fail, conv_tol, cfg_)
+        jax.debug.callback(lambda *a: ends.append(a), x0, *out)
+        return out
+
+    monkeypatch.setattr(ir, "refine", spy)
+    A, b, x = _loop_system(solver, kappa, zero_col)
+    st_ = entry.__wrapped__(*prepare(bk, A[None], b[None], x[None],
+                                     np.asarray([arm], np.int32)), cfg, bk)
+    (x0, x_end, n_outer, _, status), = ends
+    status = int(_scalar(status))
+    assert int(st_.status[0]) == status
+    assert int(st_.n_outer[0]) == int(_scalar(n_outer)) == len(steps)
+
+    def lead(v):
+        return _flat(v)[0]
+
+    want, znorm_prev, x_kept = MAXITER, np.inf, x0
+    for i, (x_in, z, x_new, fail, conv_tol) in enumerate(steps):
+        for got, kept in zip(_flat(x_in), _flat(x_kept)):
+            np.testing.assert_array_equal(got, kept)
+        znorm = np.max(np.abs(lead(z)))
+        converged = znorm <= _scalar(conv_tol) * np.max(np.abs(lead(x_new)))
+        stagnated = i > 0 and znorm >= cfg.stag_tol * znorm_prev
+        hit_max = i + 1 >= cfg.i_max
+        failed = bool(_scalar(fail)) or not np.all(np.isfinite(lead(x_new)))
+        want = (FAILED if failed else CONVERGED if converged
+                else STAGNATED if stagnated else MAXITER if hit_max
+                else want)
+        done = converged or stagnated or hit_max or failed
+        assert done == (i == len(steps) - 1)
+        x_kept = x_in if failed else x_new
+        znorm_prev = znorm
+    for got, kept in zip(_flat(x_end), _flat(x_kept)):
+        np.testing.assert_array_equal(got, kept)
+    if zero_col:
+        assert steps == [] and not np.any(_flat(x_end)[0])
+    else:
+        assert steps and want == status
+    if poison:
+        assert len(steps) == 2
+    assert status in ends_in
